@@ -20,8 +20,9 @@ strategies batched into one stacked (S, P, V) computation).  ``backend=``
 on each detector selects it explicitly ("numpy" / "jax"); the default
 "auto" uses the jitted path only when jax is ALREADY imported in the
 process, so the pure-numpy analysis layer never pays the jax import (the
-jax-free ``--smoke`` canary stays jax-free).  The ``SCALANA_DETECT_BACKEND``
-environment variable overrides the default.
+jax-free ``--smoke`` canary stays jax-free); on an accelerator it always
+takes the device path, and fails loudly if that path cannot load.  The
+``SCALANA_DETECT_BACKEND`` environment variable overrides the default.
 
 Merge strategies (``MERGE_STRATEGIES``): "mean", "median", "max", "p0",
 "cluster", and variance-weighted "var" (readings weighted 1/time_var —
@@ -64,7 +65,9 @@ def _resolve_backend(backend: Optional[str], device_live: bool = False):
     host-side stores the dispatch overhead makes the jitted path ~10x
     slower than numpy, so auto stays on numpy there; "jax" (explicitly or
     via SCALANA_DETECT_BACKEND) still forces the jitted path, and
-    "numpy" never touches jax.
+    "numpy" never touches jax.  Once the jitted path is chosen, a
+    device path that cannot be imported raises: on an accelerator a
+    silent numpy fallback would hide the device.
     """
     from_env = backend is None
     if from_env:
@@ -80,24 +83,10 @@ def _resolve_backend(backend: Optional[str], device_live: bool = False):
     if backend == "auto":
         if "jax" not in sys.modules:
             return None
-        if not device_live:
-            try:
-                import jax
-                if jax.default_backend() == "cpu":
-                    return None
-            except Exception:
-                return None
-    try:
-        from repro.core import detect_jax
-    except ImportError:        # only jax-absence falls back; bugs surface
-        if backend == "jax":
-            raise
-        return None
-    if not detect_jax.HAS_JAX:
-        if backend == "jax":
-            raise ImportError("backend='jax' requested but jax is not "
-                              "importable")
-        return None
+        import jax
+        if not device_live and jax.default_backend() == "cpu":
+            return None
+    import repro.core.detect_jax as detect_jax
     return detect_jax
 
 

@@ -35,24 +35,9 @@ _LOOP_PRIMS = {"scan", "while"}
 
 
 def _source_of(eqn) -> str:
-    try:
-        from jax._src import source_info_util
-        si = eqn.source_info
-    except Exception:                  # private API: absent on some versions
-        return ""
-    # newer jax expects the SourceInfo (reads .traceback itself); older
-    # versions took the raw Traceback — try both
-    for arg in (si, getattr(si, "traceback", si)):
-        try:
-            frame = source_info_util.user_frame(arg)
-            if frame is None:
-                frames = list(source_info_util.user_frames(arg))
-                frame = frames[0] if frames else None
-            if frame is not None:
-                return f"{frame.file_name}:{frame.start_line}"
-        except Exception:
-            continue
-    return ""
+    from jax._src import source_info_util
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    return f"{frame.file_name}:{frame.start_line}" if frame else ""
 
 
 def _sub_jaxprs(eqn) -> List[Tuple[str, Any]]:

@@ -474,25 +474,21 @@ class DeviceShardView:
         return np.ascontiguousarray(slab, dtype)
 
     def refresh(self, n_vertices: Optional[int] = None,
-                dtype=np.float64) -> int:
+                dtype=None) -> int:
         """Bring the device buffers up to date; returns bytes uploaded.
 
         ``n_vertices`` fixes the column count every block is padded or
         sliced to (defaults to the widest block).  ``dtype`` is the buffer
-        precision — float64 buffers are created under a thread-local
-        ``enable_x64`` so the upload never silently downcasts."""
-        import contextlib
-
+        precision (default: :func:`repro.core.detect_jax.precision`'s
+        pick for the backend); the buffers are created inside that
+        function's x64 context, so the upload never silently downcasts."""
         import jax.numpy as jnp
-        dtype = np.dtype(dtype)
+
+        from repro.core.detect_jax import precision
+        dtype, ctx = precision(dtype)
         if n_vertices is None:
             n_vertices = max(b._cols for b in self.blocks)
         V = int(n_vertices)
-        if dtype == np.float64:
-            from jax.experimental import enable_x64
-            ctx = enable_x64()
-        else:
-            ctx = contextlib.nullcontext()
         full = (self._time is None or self._cols != V
                 or self._dtype != dtype
                 or any(buf.shape[0] != b.n_procs

@@ -6,8 +6,8 @@ Two kernels cover the whole detection tail in one launch each:
   (row tiles) innermost/sequential: per-scale merge accumulators (count,
   sum, max, p0, inverse-variance sums) live in VMEM scratch and reduce
   across row tiles; when a scale's last tile lands its (4, V) merged
-  column is written into the M scratch stack, and the final grid step
-  appends the (optional) device-cached historical columns, derives the
+  column is written into the resident M output, and the final grid step
+  adds the (optional) device-cached historical columns, derives the
   reference step time from the "max" row, and runs the closed-form
   log-log slope fit + share/deviation flagging — all before leaving the
   kernel.  One launch replaces the merge/stack/slope dispatch chain.
@@ -27,8 +27,12 @@ kernels (``repro.core.detect_jax``), the fused jnp fast path
 (``ops.py``), and the kernel bodies themselves are defined at the top of
 this module — single source of truth, so the three paths cannot drift.
 
-Everything is dtype-generic over f32/f64 (``SCALANA_DETECT_F32``); the
-float<->ordered-integer key bridge picks uint32/uint64 to match.
+Everything is dtype-generic over f32/f64 (the TPU runs f32, see
+``repro.core.detect_jax.precision``); the float<->ordered-integer key
+bridge picks int32/int64 to match.  The kernel bodies keep to what the
+TPU compiler (Mosaic) lowers: 2-D values, static slices, parameters read
+as scalars from SMEM, (1, 1) arrays instead of vector-to-scalar stores,
+signed integer reductions only.
 """
 from __future__ import annotations
 
@@ -46,6 +50,10 @@ _IMAX = JIT_STRATEGIES.index("max")
 _ROW_TILE = 1024          # ns kernel: rows per grid step
 _COL_TILE = 128           # ab kernel: vertex columns per grid step (lanes)
 _STEP_EPS = 1e-12         # step-time clamp, matches the host reference
+# ab kernel: whole-fleet (P, 128) column blocks plus the median/top-k
+# temporaries outgrow the default 16 MiB scoped VMEM from P = 8192; a
+# v5e core has 128 MiB
+_AB_VMEM_LIMIT = 100 * 2 ** 20
 
 
 # -- shared detection math (jnp; used by legacy kernels, fused jnp path,
@@ -96,35 +104,48 @@ def merge_blocks(ts, vs) -> jax.Array:
 
 
 def slope_share_flag(M, logp, present, total_max,
-                     ideal_slope, slope_margin, min_share):
-    """(4, S, V) merged stack -> (slope, share, flagged), each (4, V).
+                     ideal_slope, slope_margin, min_share, *,
+                     keepdims: bool = False):
+    """(..., S, V) merged stack -> (slope, share, flagged), each (..., V).
+
+    ``logp`` holds the S log process counts, as (S,) or (S, 1);
+    ``present`` is the (S, V) vertex-exists mask.  Reductions run over
+    the scale axis (-2) and the reference scale is a static slice, so
+    the same formulas trace inside a TPU Pallas kernel (2-D operands,
+    ``keepdims=True`` -> (1, V) results) and in the jnp paths.
 
     ``share`` is guarded: an all-dead final scale (``total_max <= 0``)
     yields share 0 — and so flags nothing — instead of inf/nan."""
-    valid = (M > 0.0) & present[None]
-    x = logp[None, :, None]                            # (1, S, 1)
-    Y = jnp.where(valid, jnp.log(jnp.where(valid, M, 1.0)), 0.0)
-    n = valid.sum(axis=1)                              # (4, V)
-    Sx = (x * valid).sum(axis=1)
-    Sy = Y.sum(axis=1)
-    Sxx = (x * x * valid).sum(axis=1)
-    Sxy = (x * Y).sum(axis=1)
+    S = M.shape[-2]
+    valid = ((M > 0.0) & present).astype(M.dtype)
+    live = valid > 0.0
+    x = logp.reshape(-1, 1)                            # (S, 1)
+    Y = jnp.where(live, jnp.log(jnp.where(live, M, 1.0)), 0.0)
+    n = valid.sum(axis=-2, keepdims=True)
+    Sx = (x * valid).sum(axis=-2, keepdims=True)
+    Sy = Y.sum(axis=-2, keepdims=True)
+    Sxx = (x * x * valid).sum(axis=-2, keepdims=True)
+    Sxy = (x * Y).sum(axis=-2, keepdims=True)
     denom = n * Sxx - Sx ** 2
     num = n * Sxy - Sx * Sy
     slope = jnp.where((denom != 0) & (n >= 2),
                       num / jnp.where(denom != 0, denom, 1.0), 0.0)
+    last = jax.lax.slice_in_dim(M, S - 1, S, axis=M.ndim - 2)
     share = jnp.where(total_max > 0.0,
-                      M[:, -1, :] / jnp.where(total_max > 0.0,
-                                              total_max, 1.0), 0.0)
-    flagged = ((M.sum(axis=1) > 0.0)
+                      last / jnp.where(total_max > 0.0, total_max, 1.0),
+                      0.0)
+    flagged = ((M.sum(axis=-2, keepdims=True) > 0.0)
                & (slope - ideal_slope > slope_margin)
                & (share >= min_share))
-    return slope, share, flagged
+    if keepdims:
+        return slope, share, flagged
+    return slope[..., 0, :], share[..., 0, :], flagged[..., 0, :]
 
 
 def abnormal_flags(t, typical, abnorm_thd, min_share, step_time):
-    """(P, V) times + (V,) typical -> (P, V) abnormal-entry mask."""
-    active = t.max(axis=0) > 0.0
+    """(P, V) times + (V,) or (1, V) typical -> (P, V) abnormal-entry
+    mask."""
+    active = t.max(axis=0, keepdims=True) > 0.0
     over = ((t > abnorm_thd * typical) & (typical > 0.0)
             & ((t - typical) / step_time >= min_share))
     dead_typical = (typical == 0.0) & (t / step_time >= min_share)
@@ -134,46 +155,56 @@ def abnormal_flags(t, typical, abnorm_thd, min_share, step_time):
 # -- float <-> order-preserving integer keys ---------------------------
 
 def key_info(dtype) -> Tuple[jnp.dtype, int]:
-    """Unsigned key dtype + bit width for a float dtype."""
+    """Signed key dtype + bit width for a float dtype."""
     if jnp.dtype(dtype) == jnp.dtype(jnp.float64):
-        return jnp.dtype(jnp.uint64), 64
+        return jnp.dtype(jnp.int64), 64
     if jnp.dtype(dtype) == jnp.dtype(jnp.float32):
-        return jnp.dtype(jnp.uint32), 32
+        return jnp.dtype(jnp.int32), 32
     raise TypeError(f"unsupported detect dtype {dtype}")
 
 
+def key_floor(dtype) -> jax.Array:
+    """The smallest key: strictly below every float's key, -inf included
+    (top-k extraction parks taken entries there)."""
+    k, _ = key_info(dtype)
+    return jnp.array(jnp.iinfo(k).min, k)
+
+
+def _flip(b: jax.Array, bits: int) -> jax.Array:
+    # negative floats: flip the magnitude bits, so larger magnitudes
+    # order lower; an involution, so it also maps keys back to bits
+    return b ^ ((b >> (bits - 1)) & jnp.array(jnp.iinfo(b.dtype).max,
+                                             b.dtype))
+
+
 def to_key(x: jax.Array) -> jax.Array:
-    """Bitcast floats to unsigned keys whose integer order matches the
-    float total order (-inf < ... < +inf; only NaN maps to key 0/max).
+    """Bitcast floats to signed integer keys whose order matches the
+    float total order (-inf < ... < -0 < +0 < ... < +inf).
 
     Integer keys are the whole trick: XLA's single-operand integer sort
     is ~13x faster than a float sort on CPU, and the Pallas median runs
-    bitwise radix selection, which needs integer keys anyway."""
-    u, bits = key_info(x.dtype)
-    b = jax.lax.bitcast_convert_type(x, u)
-    one = jnp.array(1, u)
-    sign = jnp.array(bits - 1, u)
-    return jnp.where((b >> sign) != 0, ~b, b | (one << sign))
+    bitwise radix selection, which needs integer keys anyway.  Signed,
+    because Mosaic has no reductions over unsigned integers."""
+    k, bits = key_info(x.dtype)
+    return _flip(jax.lax.bitcast_convert_type(x, k), bits)
 
 
-def from_key(k: jax.Array, dtype) -> jax.Array:
+def from_key(key: jax.Array, dtype) -> jax.Array:
     """Inverse of :func:`to_key`."""
-    u, bits = key_info(dtype)
-    one = jnp.array(1, u)
-    sign = jnp.array(bits - 1, u)
-    b = jnp.where((k >> sign) == 0, ~k, k & ~(one << sign))
-    return jax.lax.bitcast_convert_type(b, jnp.dtype(dtype))
+    _, bits = key_info(dtype)
+    return jax.lax.bitcast_convert_type(_flip(key, bits), jnp.dtype(dtype))
 
 
 # -- non-scalable kernel ------------------------------------------------
 
 def _ns_kernel(t_ref, var_ref, hist_ref, logp_ref, present_ref, top_ref,
                par_ref, m_out, slope_out, share_out, flag_out,
-               cnt, total, mx, wsum, wt, p0, m_scr,
+               cnt, total, mx, wsum, wt, p0,
                *, n_data: int, n_hist: int):
     s = pl.program_id(0)
     p = pl.program_id(1)
-    np_ = pl.num_programs(1)
+    last_p = pl.num_programs(1) - 1
+    n_all = n_hist + n_data
     t = t_ref[0]                                       # (TP, V)
     v = var_ref[0]
 
@@ -194,34 +225,41 @@ def _ns_kernel(t_ref, var_ref, hist_ref, logp_ref, present_ref, top_ref,
     wsum[...] += w.sum(axis=0, keepdims=True)
     wt[...] += (w * t).sum(axis=0, keepdims=True)
 
-    @pl.when(p == np_ - 1)
-    def _scale_column():
-        any_pos = cnt[...] > 0
-        mean = jnp.where(any_pos, total[...] / jnp.maximum(cnt[...], 1.0),
-                         0.0)
-        mxv = jnp.where(any_pos, mx[...], 0.0)
-        p0v = jnp.where(p0[...] > 0.0, p0[...], mean)
-        varm = jnp.where(wsum[...] > 0,
-                         wt[...] / jnp.where(wsum[...] > 0, wsum[...], 1.0),
-                         0.0)
-        col = jnp.concatenate([mean, mxv, p0v, varm], axis=0)  # (4, V)
-        m_scr[:, pl.ds(s, 1), :] = col[:, None, :]
+    # Scale s's merged column lands in row n_hist + s of the resident M
+    # output.  Mosaic has no dynamic sublane store, so every data scale
+    # gets its own predicated branch with a static row.
+    for si in range(n_data):
+        @pl.when((s == si) & (p == last_p))
+        def _scale_column(row=n_hist + si):
+            any_pos = cnt[...] > 0
+            mean = jnp.where(any_pos,
+                             total[...] / jnp.maximum(cnt[...], 1.0), 0.0)
+            p0v = p0[...]
+            merged = (mean, jnp.where(any_pos, mx[...], 0.0),
+                      jnp.where(p0v > 0.0, p0v, mean),
+                      jnp.where(wsum[...] > 0,
+                                wt[...] / jnp.where(wsum[...] > 0,
+                                                    wsum[...], 1.0), 0.0))
+            for r, col in enumerate(merged):           # JIT_STRATEGIES rows
+                m_out[r, row:row + 1, :] = col
 
-    @pl.when((s == n_data - 1) & (p == np_ - 1))
+    @pl.when((s == n_data - 1) & (p == last_p))
     def _tail():
-        M = m_scr[...]                                 # (4, n_data, V)
-        if n_hist:
-            M = jnp.concatenate([hist_ref[...], M], axis=1)
-        m_out[...] = M
-        par = par_ref[0]
-        internal = (M[_IMAX, -1, :] * top_ref[0]).sum()
-        total_max = jnp.where(par[4] > 0.0, par[3], internal)
-        slope, share, flagged = slope_share_flag(
-            M, logp_ref[...][:, 0], present_ref[...] > 0.0,
-            total_max, par[0], par[1], par[2])
-        slope_out[...] = slope
-        share_out[...] = share
-        flag_out[...] = flagged.astype(slope.dtype)
+        for r in range(4):
+            if n_hist:
+                m_out[r, 0:n_hist, :] = hist_ref[r]
+        ref_max = m_out[_IMAX, n_all - 1:n_all, :]     # (1, V)
+        internal = (ref_max * top_ref[...]).sum(axis=1, keepdims=True)
+        total_max = jnp.where(par_ref[0, 4] > 0.0, par_ref[0, 3], internal)
+        logp = logp_ref[...]
+        present = present_ref[...] > 0.0
+        for r in range(4):
+            slope, share, flagged = slope_share_flag(
+                m_out[r], logp, present, total_max, par_ref[0, 0],
+                par_ref[0, 1], par_ref[0, 2], keepdims=True)
+            slope_out[r:r + 1, :] = slope
+            share_out[r:r + 1, :] = share
+            flag_out[r:r + 1, :] = flagged.astype(slope.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n_hist", "interpret"))
@@ -232,14 +270,14 @@ def ns_fused_kernel(t: jax.Array, var: jax.Array, hist: jax.Array,
     """One-launch non-scalable detection.
 
     t, var: (S_d, P, V) data scales (P padded to a row-tile multiple
-    with zero = dead rows; V padded to the lane tile).  hist: (4, H, V)
-    device-cached merged columns of completed scales, prepended to the
-    freshly merged data scales (pass a (4, 1, V) dummy with n_hist=0
-    when uncached).  logp: (S, 1) log process counts over ALL S =
-    n_hist + S_d scales; present: (S, V) 0/1; top_mask: (1, V) 0/1 root-
-    children columns; params: (1, 8) [ideal_slope, slope_margin,
-    min_share, total_max, use_total, 0, 0, 0].  Returns (M (4, S, V),
-    slope, share, flagged-as-float (4, V))."""
+    with zero = dead rows).  hist: (4, H, V) device-cached merged columns
+    of completed scales, prepended to the freshly merged data scales
+    (pass a (4, 1, V) dummy with n_hist=0 when uncached).  logp: (S, 1)
+    log process counts over ALL S = n_hist + S_d scales; present: (S, V)
+    0/1; top_mask: (1, V) 0/1 root-children columns; params: (1, 8)
+    [ideal_slope, slope_margin, min_share, total_max, use_total, 0, 0,
+    0], read as scalars from SMEM.  Returns (M (4, S, V), slope, share,
+    flagged-as-float (4, V))."""
     S_d, P, V = t.shape
     TP = P if P <= _ROW_TILE else _ROW_TILE
     assert P % TP == 0, (P, TP)
@@ -257,7 +295,7 @@ def ns_fused_kernel(t: jax.Array, var: jax.Array, hist: jax.Array,
             pl.BlockSpec((S_t, 1), lambda s, p: (0, 0)),
             pl.BlockSpec((S_t, V), lambda s, p: (0, 0)),
             pl.BlockSpec((1, V), lambda s, p: (0, 0)),
-            pl.BlockSpec((1, 8), lambda s, p: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((4, S_t, V), lambda s, p: (0, 0, 0)),
@@ -271,68 +309,90 @@ def ns_fused_kernel(t: jax.Array, var: jax.Array, hist: jax.Array,
             jax.ShapeDtypeStruct((4, V), dt),
             jax.ShapeDtypeStruct((4, V), dt),
         ],
-        scratch_shapes=[pltpu.VMEM((1, V), dt) for _ in range(6)]
-        + [pltpu.VMEM((4, S_d, V), dt)],
+        scratch_shapes=[pltpu.VMEM((1, V), dt) for _ in range(6)],
         interpret=interpret,
     )(t, var, hist, logp, present, top_mask, params)
 
 
 # -- abnormal kernel ----------------------------------------------------
 
-def _select_rank(keys: jax.Array, rank: jax.Array, nbits: int) -> jax.Array:
+def _max2(x: jax.Array) -> jax.Array:
+    """Max of a 2-D array as a (1, 1) array (sublanes, then lanes)."""
+    return x.max(axis=0, keepdims=True).max(axis=1, keepdims=True)
+
+
+def _min2(x: jax.Array) -> jax.Array:
+    return x.min(axis=0, keepdims=True).min(axis=1, keepdims=True)
+
+
+def _select_rank(keys: jax.Array, rank: jax.Array, nbits: int,
+                 count_dtype) -> jax.Array:
     """Per-column rank-``rank`` order statistic of integer keys.
 
-    MSB-first radix selection: ``eq`` tracks the rows still matching the
-    decided high bits; each pass counts how many of those have the
-    current bit clear and descends left or right.  ``nbits`` counting
-    passes over the (P, TV) tile — no sort primitive needed, which is
-    what lets the median run inside a TPU Pallas kernel at all."""
+    MSB-first radix selection: ``eq`` marks (0/1) the rows still
+    matching the decided high bits; each pass counts how many of those
+    have the current bit clear and descends left or right.  ``nbits``
+    counting passes over the (P, TV) tile — no sort primitive needed,
+    which is what lets the median run inside a TPU Pallas kernel at
+    all.  The keys are signed, so the sign bit is read inverted (set =
+    lower) and flipped back into the result.  ``rank`` is (1, 1);
+    counts and ranks are exact integers in ``count_dtype`` (a float: P
+    stays far below 2**24)."""
     u = keys.dtype
     one = jnp.array(1, u)
+    zero = jnp.array(0, u)
+    sign = jnp.array(jnp.iinfo(u).min, u)
     prefix = jnp.zeros((1, keys.shape[1]), u)
-    rr = jnp.full((1, keys.shape[1]), rank, jnp.int32)
-    eq = jnp.ones(keys.shape, jnp.bool_)
+    rr = jnp.broadcast_to(rank, (1, keys.shape[1]))
+    eq = jnp.ones(keys.shape, count_dtype)
 
     def body(i, st):
         prefix, rr, eq = st
-        bit = jnp.array(nbits - 1, jnp.int32) - i
-        kb = ((keys >> bit.astype(u)) & one) != 0      # (P, TV)
-        cnt0 = (eq & ~kb).sum(axis=0, keepdims=True, dtype=jnp.int32)
+        bit = (nbits - 1 - i).astype(u)
+        kb = (((keys >> bit) & one) != zero) != (i == 0)   # (P, TV)
+        cnt0 = jnp.where(kb, 0.0, eq).sum(axis=0, keepdims=True)
         go = rr >= cnt0                                # (1, TV)
-        prefix = jnp.where(go, prefix | (one << bit.astype(u)), prefix)
+        prefix = jnp.where(go, prefix | (one << bit), prefix)
         rr = jnp.where(go, rr - cnt0, rr)
-        eq = eq & (kb == go)
+        eq = jnp.where(kb == go, eq, 0.0)
         return prefix, rr, eq
 
     prefix, _, _ = jax.lax.fori_loop(0, nbits, body, (prefix, rr, eq))
-    return prefix                                      # (1, TV)
+    return prefix ^ sign                               # (1, TV)
 
 
 def _extract_topk(skeys, sidx, seed_keys, seed_idx, k: int):
-    """k rounds of (max key, min index among maxes) extraction, seeded
-    with the running cross-tile best; extracted entries drop to key 0
-    (strictly below every real score key, -inf included)."""
+    """k rounds of (max key, min index among maxes) extraction over the
+    (P, TV) tile and the (1, k) running cross-tile best; extracted
+    entries drop to the key floor (strictly below every real score key,
+    -inf included).  Everything stays 2-D: the winners are written into
+    (1, k) rows by lane mask, not by dynamic index."""
     u = skeys.dtype
+    floor = jnp.array(jnp.iinfo(u).min, u)
     imax = jnp.iinfo(jnp.int32).max
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
     def body(i, st):
-        sk, si, ok, oi = st
-        m = sk.max()
-        pick = jnp.where(sk == m, si, imax).min()
-        sk = jnp.where((sk == m) & (si == pick), jnp.array(0, u), sk)
-        return sk, si, ok.at[i].set(m), oi.at[i].set(pick)
+        sk, kk, ok, oi = st
+        m = jnp.maximum(_max2(sk), _max2(kk))          # (1, 1)
+        pick = jnp.minimum(_min2(jnp.where(sk == m, sidx, imax)),
+                           _min2(jnp.where(kk == m, seed_idx, imax)))
+        sk = jnp.where((sk == m) & (sidx == pick), floor, sk)
+        kk = jnp.where((kk == m) & (seed_idx == pick), floor, kk)
+        ok = jnp.where(lane == i, m, ok)
+        oi = jnp.where(lane == i, pick, oi)
+        return sk, kk, ok, oi
 
-    ok = jnp.zeros((k,), u)
-    oi = jnp.full((k,), imax, jnp.int32)
-    sk = jnp.concatenate([skeys.reshape(-1), seed_keys])
-    si = jnp.concatenate([sidx.reshape(-1), seed_idx])
-    _, _, ok, oi = jax.lax.fori_loop(0, k, body, (sk, si, ok, oi))
+    ok = jnp.full((1, k), floor)
+    oi = jnp.full((1, k), imax, jnp.int32)
+    _, _, ok, oi = jax.lax.fori_loop(
+        0, k, body, (skeys, seed_keys, ok, oi))
     return ok, oi
 
 
 def _ab_kernel(t_ref, valid_ref, top_ref, par_ref,
                order_out, score_out, count_out, typ_out,
-               step_scr, step_val, best_k, best_i, cnt_scr,
+               step_scr, best_k, best_i, cnt_scr,
                *, k: int, nv: int, tv: int, nbits: int):
     ph = pl.program_id(0)
     cv = pl.program_id(1)
@@ -350,29 +410,26 @@ def _ab_kernel(t_ref, valid_ref, top_ref, par_ref,
     def _accum_step():
         step_scr[...] += (t * top_ref[...]).sum(axis=1, keepdims=True)
 
-    @pl.when((ph == 0) & (cv == nv - 1))
-    def _finish_step():
-        par = par_ref[0]
-        sv = jnp.where(vb[:, 0], step_scr[...][:, 0], 0.0).max()
-        sv = jnp.where(sv > 0.0, sv, jnp.array(_STEP_EPS, dt))
-        step_val[0, 0] = jnp.where(par[3] > 0.0, par[2], sv)
-
     @pl.when(ph == 1)
     def _detect():
-        par = par_ref[0]
-        abnorm_thd, min_share = par[0], par[1]
-        step = step_val[0, 0]
-        n_live = jnp.maximum(validf.sum(), 1.0).astype(jnp.int32)
+        sv = _max2(jnp.where(vb, step_scr[...], 0.0))  # (1, 1)
+        sv = jnp.where(sv > 0.0, sv, jnp.array(_STEP_EPS, dt))
+        step = jnp.where(par_ref[0, 3] > 0.0, par_ref[0, 2], sv)
+        abnorm_thd, min_share = par_ref[0, 0], par_ref[0, 1]
+        n_live = jnp.maximum(validf.sum(axis=0, keepdims=True), 1.0)
         keys = jnp.where(vb, to_key(t), to_key(jnp.full_like(t, jnp.inf)))
-        lo = from_key(_select_rank(keys, (n_live - 1) // 2, nbits), dt)
-        hi = from_key(_select_rank(keys, n_live // 2, nbits), dt)
+        lo = from_key(_select_rank(keys, jnp.floor((n_live - 1.0) * 0.5),
+                                   nbits, dt), dt)
+        hi = from_key(_select_rank(keys, jnp.floor(n_live * 0.5),
+                                   nbits, dt), dt)
         typical = 0.5 * (lo + hi)                      # (1, TV)
         typ_out[...] = typical
         tm = jnp.where(vb, t, 0.0)
-        flags = abnormal_flags(tm, typical[0], abnorm_thd, min_share,
+        flags = abnormal_flags(tm, typical, abnorm_thd, min_share,
                                step) & vb
-        add = flags.sum(dtype=jnp.int32)
-        cnt_scr[0, 0] = jnp.where(cv == 0, add, cnt_scr[0, 0] + add)
+        add = flags.astype(jnp.int32).sum(axis=0, keepdims=True).sum(
+            axis=1, keepdims=True)                     # (1, 1)
+        cnt_scr[...] = jnp.where(cv == 0, add, cnt_scr[...] + add)
 
         neg = to_key(jnp.full_like(t, -jnp.inf))
         skeys = jnp.where(flags, to_key(tm - typical), neg)
@@ -382,18 +439,19 @@ def _ab_kernel(t_ref, valid_ref, top_ref, par_ref,
         lidx = (cv * tv + cols) * P + rows             # global vid-major
 
         imax = jnp.iinfo(jnp.int32).max
-        seed_k = jnp.where(cv == 0, jnp.zeros((k,), u), best_k[0])
-        seed_i = jnp.where(cv == 0, jnp.full((k,), imax, jnp.int32),
-                           best_i[0])
+        seed_k = jnp.where(cv == 0, jnp.full((1, k), key_floor(dt)),
+                           best_k[...])
+        seed_i = jnp.where(cv == 0, jnp.full((1, k), imax, jnp.int32),
+                           best_i[...])
         ok, oi = _extract_topk(skeys, lidx, seed_k, seed_i, k)
-        best_k[...] = ok[None]
-        best_i[...] = oi[None]
+        best_k[...] = ok
+        best_i[...] = oi
 
         @pl.when(cv == nv - 1)
         def _emit():
             order_out[...] = best_i[...]
             score_out[...] = from_key(best_k[...], dt)
-            count_out[0, 0] = cnt_scr[0, 0]
+            count_out[...] = cnt_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -403,16 +461,18 @@ def ab_fused_kernel(t: jax.Array, valid: jax.Array, top_mask: jax.Array,
 
     valid: (P, 1) 0/1 live-row mask (degraded fleets; all-ones
     otherwise).  top_mask: (1, V) 0/1 step-time columns.  params: (1, 8)
-    [abnorm_thd, min_share, step_time, use_step, 0...].  V must be a
-    lane-tile multiple (ops pads with zero columns — dead, never
-    flagged, and their -inf scores rank after every real entry).
-    Returns (order (1, k) int32 flat vid-major, scores (1, k), count
-    (1, 1) int32, typical (1, V)); entries past the flagged count are
-    the reference's -inf tail, exactly as the stable argsort yields.
+    [abnorm_thd, min_share, step_time, use_step, 0...], read as scalars
+    from SMEM.  V must be a lane-tile multiple (ops pads with zero
+    columns — dead, never flagged, and their -inf scores rank after
+    every real entry).  Returns (order (1, k) int32 flat vid-major,
+    scores (1, k), count (1, 1) int32, typical (1, V)); entries past the
+    flagged count are the reference's -inf tail, exactly as the stable
+    argsort yields.
 
-    The whole fleet's rows sit in one VMEM block per column tile —
-    (P, 128) f32 at 64k procs is 32 MB, so beyond ~32k procs use f32 or
-    shrink the column tile; row-tiled median is future work."""
+    The whole fleet's rows sit in one VMEM block per column tile, next
+    to several (P, 128) temporaries of the median and the top-k, so the
+    scoped VMEM limit is raised to fit them; a row-tiled median is
+    future work."""
     P, V = t.shape
     tv = V if V <= _COL_TILE else _COL_TILE
     assert V % tv == 0, (V, tv)
@@ -427,7 +487,7 @@ def ab_fused_kernel(t: jax.Array, valid: jax.Array, top_mask: jax.Array,
             pl.BlockSpec((P, tv), lambda ph, cv: (0, cv)),
             pl.BlockSpec((P, 1), lambda ph, cv: (0, 0)),
             pl.BlockSpec((1, tv), lambda ph, cv: (0, cv)),
-            pl.BlockSpec((1, 8), lambda ph, cv: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, k), lambda ph, cv: (0, 0)),
@@ -443,10 +503,11 @@ def ab_fused_kernel(t: jax.Array, valid: jax.Array, top_mask: jax.Array,
         ],
         scratch_shapes=[
             pltpu.VMEM((P, 1), dt),
-            pltpu.VMEM((1, 1), dt),
             pltpu.VMEM((1, k), u),
             pltpu.VMEM((1, k), jnp.int32),
             pltpu.VMEM((1, 1), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_AB_VMEM_LIMIT),
         interpret=interpret,
     )(t, valid, top_mask, params)

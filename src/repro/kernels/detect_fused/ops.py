@@ -10,7 +10,7 @@ Three ops cover the detection tail, each ONE logical launch:
 * :func:`fused_abnormal` — step time + masked median + flags + stable
   top-k over the (P, V) matrix (blockwise and degraded-fleet variants).
 
-Dispatch (``interpret`` argument):
+Dispatch (``interpret`` argument, resolved by :func:`kernel_mode`):
 
 * ``None``  — compiled Pallas on TPU, else the fused-jnp fast path (one
   ``jax.jit`` executable per op; Pallas interpret mode is far slower
@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from repro.kernels.detect_fused.kernel import (
     _COL_TILE, _ROW_TILE, _STEP_EPS, ab_fused_kernel, abnormal_flags,
-    from_key, key_info, merge_all_stack, merge_blocks, ns_fused_kernel,
+    from_key, key_floor, merge_all_stack, merge_blocks, ns_fused_kernel,
     slope_share_flag, to_key)
 from repro.core.detect import JIT_STRATEGIES
 
@@ -67,16 +67,10 @@ def reset_launch_counts() -> None:
     launch_counts.clear()
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:                                  # pragma: no cover
-        return False
-
-
-def _mode(interpret: Optional[bool]) -> str:
+def kernel_mode(interpret: Optional[bool] = None) -> str:
+    """How the fused ops run: "pallas" (compiled), "interpret" or "jnp"."""
     if interpret is None:
-        return "pallas" if _on_tpu() else "jnp"
+        return "pallas" if jax.default_backend() == "tpu" else "jnp"
     return "interpret" if interpret else "pallas"
 
 
@@ -86,15 +80,18 @@ def _topk_tournament(score: jax.Array, k: int):
     """Exact replacement for ``argsort(-flat, stable=True)[:k]`` over the
     vid-major flattening: block maxima + k extraction rounds on integer
     keys.  Ties rank by ascending flat index (argmax returns the FIRST
-    max), and extracted entries drop to key 0 — strictly below every
-    real score key, -inf included, so the -inf tail fills in ascending
-    index order exactly like the stable argsort."""
+    max), and extracted and padding entries sit at the key floor —
+    strictly below every real score key, -inf included, so the -inf
+    tail fills in ascending index order exactly like the stable
+    argsort."""
     flat = score.T.reshape(-1)
     n = flat.shape[0]
     keys = to_key(flat)
+    floor = key_floor(score.dtype)
     B = 128
     nb = -(-n // B)
-    kp = jnp.pad(keys, (0, nb * B - n)).reshape(nb, B)
+    kp = jnp.pad(keys, (0, nb * B - n),
+                 constant_values=floor).reshape(nb, B)
 
     def body(i, st):
         kb, order, vals = st
@@ -102,7 +99,7 @@ def _topk_tournament(score: jax.Array, k: int):
         row = kb[j]
         i2 = jnp.argmax(row)
         gidx = j.astype(jnp.int32) * B + i2.astype(jnp.int32)
-        kb = kb.at[j, i2].set(jnp.array(0, kb.dtype))
+        kb = kb.at[j, i2].set(floor)
         return kb, order.at[i].set(gidx), vals.at[i].set(row[i2])
 
     order = jnp.zeros((k,), jnp.int32)
@@ -170,8 +167,13 @@ def _ns_live_jnp(ts, vs, hist, logp, present, top_idx, params):
 
 # -- padding helpers for the Pallas path -------------------------------
 
+def _lanes(V: int) -> int:
+    """V rounded up to whole lane tiles (the ab kernel's column width)."""
+    return -(-V // _COL_TILE) * _COL_TILE
+
+
 def _pad_cols(a: jax.Array, V: int) -> jax.Array:
-    Vp = V if V <= _COL_TILE else -(-V // _COL_TILE) * _COL_TILE
+    Vp = _lanes(V)
     if Vp == V:
         return a
     pad = [(0, 0)] * (a.ndim - 1) + [(0, Vp - V)]
@@ -189,8 +191,7 @@ def _pad_rows(a: jax.Array, P: int, axis: int) -> jax.Array:
 
 
 def _top_mask(top_idx, V: int, dtype) -> jax.Array:
-    Vp = V if V <= _COL_TILE else -(-V // _COL_TILE) * _COL_TILE
-    m = jnp.zeros((1, Vp), dtype)
+    m = jnp.zeros((1, _lanes(V)), dtype)
     if top_idx is not None and top_idx.shape[0]:
         m = m.at[0, top_idx].set(1.0)
     return m
@@ -225,7 +226,7 @@ def fused_abnormal(ts: Sequence[jax.Array], top_idx: Optional[jax.Array],
     if k_eff == 0:
         return (jnp.zeros((0,), jnp.int32), jnp.zeros((0,), dtype),
                 jnp.zeros((), jnp.int32), jnp.zeros((V,), dtype))
-    mode = _mode(interpret)
+    mode = kernel_mode(interpret)
     _note_launch("abnormal")
     use_step = step_time is not None
     if mode == "jnp":
@@ -267,7 +268,7 @@ def fused_non_scalable(t: jax.Array, var: jax.Array, logp: jax.Array,
     time/variance matrices.  ``total_max`` (host-derived reference step
     time) wins over the in-kernel derivation from ``top_idx``.  Returns
     (M (4, S, V), slope (4, V), share (4, V), flagged (4, V) bool)."""
-    mode = _mode(interpret)
+    mode = kernel_mode(interpret)
     _note_launch("non_scalable")
     dtype = t.dtype
     use_total = total_max is not None
@@ -306,7 +307,7 @@ def fused_non_scalable_live(ts: Sequence[jax.Array],
     historical (4, H, V) stack, and run the slope/share/flag tail — all
     one launch.  ``logp`` / ``present`` cover all H + 1 scales (live
     last).  Returns (M (4, H + 1, V), slope, share, flagged bool)."""
-    mode = _mode(interpret)
+    mode = kernel_mode(interpret)
     _note_launch("non_scalable_live")
     ts, vs = tuple(ts), tuple(vs)
     dtype = ts[0].dtype

@@ -38,8 +38,7 @@ import jax
 from repro.configs import ARCHS, SHAPES, get as get_config, shape_applicable
 from repro.core.hlo_walk import analyze_hlo
 from repro.launch.mesh import (HBM_BANDWIDTH, ICI_BANDWIDTH, PEAK_FLOPS_BF16,
-                               cost_analysis_dict, make_production_mesh,
-                               mesh_chip_count)
+                               make_production_mesh, mesh_chip_count)
 from repro.launch.shardings import build_cell
 
 ARTIFACT_DIR = "artifacts/dryrun"
@@ -80,7 +79,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     t_compile = time.time() - t0
 
     ma = compiled.memory_analysis()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     hlo_text = compiled.as_text()
     hw = analyze_hlo(hlo_text)          # trip-count-exact per-device costs
 

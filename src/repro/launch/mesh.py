@@ -8,19 +8,11 @@ the real single-CPU device, not the dry-run's 512 placeholders).
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
-
 import jax
-
-try:                              # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:               # older jax: Auto is the only behavior
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -40,15 +32,6 @@ def make_host_mesh(model_axis: int = 1):
 
 def mesh_chip_count(mesh) -> int:
     return mesh.devices.size
-
-
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """compiled.cost_analysis() as a flat dict across jax versions
-    (older jax returns a list with one dict per device)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
 
 
 # TPU v5e hardware constants for the roofline (per chip).

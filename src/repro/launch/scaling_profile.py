@@ -7,6 +7,12 @@ This is the paper's four-step usage (§V) mapped to JAX:
      (worker subprocesses with different ``--xla_force_host_platform_
      device_count``; each runs the REAL sharded train step and records
      per-PSG-vertex times via GraphProfiler).
+
+This is a CPU emulation of job scales: the forced device count exists
+only on the host platform.  On an accelerator host every worker would
+see the real chips, so a worker whose device count differs from the
+scale it was asked for exits non-zero instead of writing a profile
+under the wrong scale.
   3. *ScalAna-detect*  — fit per-vertex log-log scaling curves across the
      measured series, flag non-scalable + abnormal vertices, run
      backtracking root-cause detection.
@@ -46,6 +52,11 @@ def worker(args) -> None:
     from repro.optim.adamw import adamw_init
 
     n = jax.device_count()
+    if n != args.scale:
+        raise SystemExit(f"[worker] asked for scale {args.scale} but jax "
+                         f"sees {n} {jax.default_backend()} devices; job "
+                         f"scales are emulated on the CPU only "
+                         f"(JAX_PLATFORMS=cpu)")
     cfg = get_smoke(args.arch).replace(remat=False)
     run = RunConfig(arch=args.arch)
     model = build_model(cfg)
@@ -114,6 +125,8 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--sample-every", type=int, default=4)
+    ap.add_argument("--scale", type=int, default=1,
+                    help="worker: the device count it must see")
     ap.add_argument("--out", default="")
     ap.add_argument("--out-dir", default=ARTIFACT_DIR)
     args = ap.parse_args()
@@ -128,7 +141,8 @@ def main() -> None:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
         cmd = [sys.executable, "-m", "repro.launch.scaling_profile",
-               "--worker", "--arch", args.arch, "--steps", str(args.steps),
+               "--worker", "--scale", str(n), "--arch", args.arch,
+               "--steps", str(args.steps),
                "--batch", str(args.batch), "--seq", str(args.seq),
                "--sample-every", str(args.sample_every), "--out", out]
         print(f"[scaling_profile] scale {n}...", flush=True)

@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_smoke, get as get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.api import build_model
 from repro.serving import Request, ServingEngine
 
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_smoke(args.arch)
     model = build_model(cfg)

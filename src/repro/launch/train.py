@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.configs import SHAPES, get as get_config, get_smoke
 from repro.configs.base import RunConfig, ShapeConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.training import Trainer
 
 
@@ -45,6 +46,7 @@ def main() -> None:
     ap.add_argument("--report", action="store_true")
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args()
+    use_compile_cache()
 
     run = RunConfig(
         arch=args.arch, shape=args.shape, total_steps=args.steps,

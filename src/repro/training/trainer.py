@@ -178,7 +178,15 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def _put_batch(self, np_batch: Dict[str, np.ndarray]):
-        return jax.tree.map(jnp.asarray, np_batch)
+        if self.mesh is None:
+            return jax.tree.map(jnp.asarray, np_batch)
+        from jax.sharding import NamedSharding
+
+        def put(x):
+            axes = ("batch",) + (None,) * (x.ndim - 1)
+            return jax.device_put(x, NamedSharding(
+                self.mesh, spec_for(axes, x.shape, self.mesh, self.rules)))
+        return jax.tree.map(put, np_batch)
 
     def enable_scalana(self, state: TrainState,
                        example_batch: Dict[str, jax.Array]) -> None:
@@ -193,6 +201,16 @@ class Trainer:
               state: Optional[TrainState] = None,
               resume: bool = True,
               step_timeout_s: float = 0.0) -> TrainState:
+        """Run ``num_steps`` steps.  With a mesh, the loop runs under its
+        logical rules and the state and every batch are placed with their
+        NamedShardings (data-parallel batch, rule-sharded parameters)
+        instead of landing whole on the default device."""
+        if self.mesh is None:
+            return self._train(num_steps, state, resume, step_timeout_s)
+        with use_rules(self.mesh, self.rules):
+            return self._train(num_steps, state, resume, step_timeout_s)
+
+    def _train(self, num_steps, state, resume, step_timeout_s):
         num_steps = num_steps or self.run.total_steps
         start_step = 0
         if state is None:
@@ -203,6 +221,8 @@ class Trainer:
                 if restored is not None:
                     start_step, tree, _ = restored
                     state = jax.tree.map(jnp.asarray, tree)
+        if self.mesh is not None:
+            state = jax.device_put(state, self.state_shardings(state))
 
         if self.run.scalana and self.profiler is None:
             batch0 = self._put_batch(self.dataset.batch(start_step))

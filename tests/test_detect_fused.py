@@ -25,7 +25,6 @@ import pytest
 jax = pytest.importorskip("jax")
 
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import detect_abnormal, detect_non_scalable, detect_jax
 from repro.core.inject import simulate
@@ -33,8 +32,11 @@ from repro.kernels.detect_fused import ops, ref
 
 from tests.test_device_detect import _ab_key, _step_psg
 
-if not detect_jax.HAS_JAX:                         # pragma: no cover
-    pytest.skip("jax not importable", allow_module_level=True)
+
+def enable_x64():
+    """The float64 detection context (the CPU parity precision)."""
+    return detect_jax.precision(np.float64)[1]
+
 
 MODES = [(None, "jnp"), (True, "interpret")]
 ARGS = dict(ideal_slope=0.0, slope_margin=0.05, min_share=0.01)

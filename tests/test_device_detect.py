@@ -281,8 +281,6 @@ def test_total_max_zero_yields_zero_share_no_flags(backend):
 
 def test_non_scalable_kernel_guards_total_max_directly():
     detect_jax = pytest.importorskip("repro.core.detect_jax")
-    if not detect_jax.HAS_JAX:
-        pytest.skip("jax not importable")
     S, P, V = 2, 3, 4
     rng = np.random.default_rng(1)
     t = rng.uniform(0.1, 1.0, (S, P, V))
@@ -473,3 +471,31 @@ def test_refresh_failure_on_full_upload_leaves_view_unprimed(monkeypatch):
     host = np.concatenate([b.time for b in view.blocks], axis=0)
     dev = np.concatenate([np.asarray(t) for t in view.time_blocks()], axis=0)
     np.testing.assert_array_equal(dev, host)
+
+
+# ---------------------------------------------------------------------------
+# routing: no silent numpy fallback on an accelerator
+# ---------------------------------------------------------------------------
+
+def test_auto_backend_raises_on_accelerator_when_device_path_unusable(
+        monkeypatch):
+    """On a non-CPU backend "auto" must take the device path or fail: a
+    numpy fallback there would hide the device.  The platform and an
+    unimportable device path are steered here."""
+    jax = pytest.importorskip("jax")
+    import sys
+
+    import repro.core
+    from repro.core.detect import _resolve_backend
+
+    monkeypatch.delattr(repro.core, "detect_jax", raising=False)
+    monkeypatch.setitem(sys.modules, "repro.core.detect_jax", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ImportError):
+        _resolve_backend("auto")
+    _, _, sharded = _sim_pair(8, 2, inject={(3, 2): 0.5})
+    with pytest.raises(ImportError):
+        detect_abnormal(sharded)
+    # a CPU host keeps host-side stores on numpy without touching it
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert _resolve_backend("auto") is None

@@ -81,7 +81,6 @@ def test_split_computations_finds_entry():
 # ---------------------------------------------------------------------------
 
 def _mesh22():
-    # AxisType only exists in newer jax; Auto is the default behavior anyway
     from repro.launch.mesh import _mesh
     return _mesh((1, 1), ("data", "model"))
 

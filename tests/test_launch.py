@@ -39,8 +39,7 @@ def test_cell_lowers_and_compiles(arch, kind, monkeypatch):
     mesh = make_host_mesh()
     cell = build_cell(arch, shape.name, mesh, cfg=cfg, donate=False)
     compiled = cell.lower().compile()
-    from repro.launch.mesh import cost_analysis_dict
-    assert cost_analysis_dict(compiled).get("flops", 0) > 0
+    assert compiled.cost_analysis().get("flops", 0) > 0
     cost = analyze_hlo(compiled.as_text())
     assert cost.dot_flops > 0
 
@@ -83,3 +82,32 @@ def test_shape_rules_are_pure():
     for s in SHAPES.values():
         rules_for_shape(s, get_smoke("tinyllama-1.1b"), mesh)
     assert ax.DEFAULT_RULES == before
+
+
+def test_scaling_profile_worker_refuses_a_wrong_device_count():
+    """Job scales are emulated with forced CPU device counts; a worker
+    that sees another count (an accelerator host) must fail, not write
+    its profile under the scale it was asked for."""
+    import argparse
+
+    from repro.launch.scaling_profile import worker
+    args = argparse.Namespace(scale=jax.device_count() + 1,
+                              arch="mamba2-130m")
+    with pytest.raises(SystemExit, match="asked for scale"):
+        worker(args)
+
+
+def test_compile_cache_env_wins_else_fixed_repo_dir(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = str(compile_cache.REPO_CACHE_DIR)
+        assert compile_cache.use_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert compile_cache.REPO_CACHE_DIR.name == ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -36,8 +36,8 @@ def main() -> int:
         print("detect-fused smoke: jax not installed — skipped")
         return 0
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
+    from repro.core.detect_jax import precision
     from repro.kernels.detect_fused import ops, ref
 
     rng = np.random.default_rng(0)
@@ -56,7 +56,8 @@ def main() -> int:
         print(f"{'ok  ' if ok else 'FAIL'} {name} (interpret)")
         failures += not ok
 
-    with enable_x64():
+    _, x64 = precision(np.float64)
+    with x64:
         logp = jnp.asarray(np.log(np.asarray(scales, np.float64)))
         tj, vj = jnp.asarray(t), jnp.asarray(var)
         pj, topj = jnp.asarray(present), jnp.asarray(top)
