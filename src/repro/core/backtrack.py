@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.core.detect import Abnormal, NonScalable
 from repro.core.graph import BRANCH, CALL, COMM, LOOP, PPG, PSG, ROOT
+from repro.core.spans import spanned
 
 Node = Tuple[int, int]                     # (proc, vid)
 
@@ -461,6 +462,7 @@ def backtrack_batched(ppg: PPG, non_scalable: Sequence[NonScalable],
 BACKTRACK_MODES = ("auto", "batched", "scalar")
 
 
+@spanned("backtrack")
 def backtrack(ppg: PPG, non_scalable: Sequence[NonScalable],
               abnormal: Sequence[Abnormal], *,
               mode: str = "auto") -> List[Path]:
@@ -526,6 +528,7 @@ def _busy_matrix(ppg: PPG) -> np.ndarray:
     return ppg.times_matrix() - _wait_matrix(ppg)
 
 
+@spanned("root_causes")
 def root_causes(paths: Sequence[Path], psg: PSG, top_k: int = 5,
                 ppg: Optional[PPG] = None) -> List[Tuple[Node, str, str]]:
     """Deduplicated root-cause vertices (node, name, source).

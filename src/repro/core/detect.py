@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.core.graph import COMM, COMP, LOOP, PPG
 from repro.core.shard import ShardedStore
+from repro.core.spans import spanned
 
 MERGE_STRATEGIES = ("mean", "median", "max", "p0", "cluster", "var")
 
@@ -258,6 +259,7 @@ def fit_slopes(scales: Sequence[int], M: np.ndarray,
     return _fit_slopes(scales, np.asarray(M, float), np.asarray(valid, bool))
 
 
+@spanned("detect.non_scalable")
 def detect_non_scalable(series: Mapping[int, PPG], *,
                         ideal_slope: float = -1.0,
                         slope_margin: float = 0.35,
@@ -380,6 +382,7 @@ def detect_non_scalable(series: Mapping[int, PPG], *,
     return out[:top_k]
 
 
+@spanned("detect.abnormal")
 def detect_abnormal(ppg: PPG, *, abnorm_thd: float = 1.3,
                     min_share: float = 0.01,
                     top_k: int = 20,

@@ -62,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.detect import JIT_STRATEGIES, VAR_EPS
+from repro.core.spans import span
 # The pure merge/slope/flag formulas live in
 # ``repro.kernels.detect_fused.kernel`` — single source of truth shared
 # by these legacy kernels (kept for parity tests and as the unfused
@@ -372,7 +373,6 @@ def abnormal_topk_view(view, n_vertices: int, top: Sequence[int],
             live[:n_live] = np.asarray(live_rows, np.int32)
             valid[:n_live] = True
         if fused:
-            view.kernel_launches += 1
             if live_rows is None:
                 order, _, count, typical = _fused.fused_abnormal(
                     ts, top_d, float(abnorm_thd), float(min_share),
@@ -389,9 +389,10 @@ def abnormal_topk_view(view, n_vertices: int, top: Sequence[int],
             order, _, count, typical = _abnormal_topk_blocks_live_kernel(
                 ts, jnp.asarray(live), jnp.asarray(valid), top_d,
                 float(abnorm_thd), float(min_share), int(k))
-        n_flagged = int(count)
-        order = np.asarray(order[:min(int(k), n_flagged)])
-        typical = np.asarray(typical)
+        with span("detect.readback"):
+            n_flagged = int(count)
+            order = np.asarray(order[:min(int(k), n_flagged)])
+            typical = np.asarray(typical)
     return order // n_procs, order % n_procs, typical, n_flagged
 
 
@@ -433,12 +434,10 @@ def non_scalable_views(scales: Sequence[int], views: Sequence,
                     col = _fused.merge_scale_column(
                         tuple(v.time_blocks()), tuple(v.var_blocks()))
                     v.cache_merged_column(col)
-                    v.kernel_launches += 1
                 cols.append(col)
             hist = (jnp.stack(cols, axis=1) if cols
                     else jnp.zeros((4, 0, int(n_vertices)), dtype))
             live = views[-1]
-            live.kernel_launches += 1
             M, slope, share, flagged = _fused.fused_non_scalable_live(
                 tuple(live.time_blocks()), tuple(live.var_blocks()),
                 hist, jnp.asarray(logp), jnp.asarray(present),
@@ -446,15 +445,15 @@ def non_scalable_views(scales: Sequence[int], views: Sequence,
                 ideal_slope=float(ideal_slope),
                 slope_margin=float(slope_margin),
                 min_share=float(min_share))
+        else:
+            M = jnp.stack(
+                [_merge_blocks_kernel(tuple(v.time_blocks()),
+                                      tuple(v.var_blocks())) for v in views],
+                axis=1)                                    # (4, S, V)
+            slope, share, flagged = _slope_flag_from_M_kernel(
+                M, jnp.asarray(logp), jnp.asarray(present),
+                jnp.asarray(np.asarray(top, np.int32)),
+                float(ideal_slope), float(slope_margin), float(min_share))
+        with span("detect.readback"):
             return (np.asarray(M)[si], np.asarray(slope)[si],
                     np.asarray(share)[si], np.asarray(flagged)[si])
-        M = jnp.stack(
-            [_merge_blocks_kernel(tuple(v.time_blocks()),
-                                  tuple(v.var_blocks())) for v in views],
-            axis=1)                                        # (4, S, V)
-        slope, share, flagged = _slope_flag_from_M_kernel(
-            M, jnp.asarray(logp), jnp.asarray(present),
-            jnp.asarray(np.asarray(top, np.int32)),
-            float(ideal_slope), float(slope_margin), float(min_share))
-        return (np.asarray(M)[si], np.asarray(slope)[si],
-                np.asarray(share)[si], np.asarray(flagged)[si])

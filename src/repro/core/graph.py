@@ -34,6 +34,8 @@ from typing import (Any, Dict, Iterable, Iterator, List, Mapping, Optional,
 
 import numpy as np
 
+from repro.core.spans import span
+
 LOOP = "Loop"
 BRANCH = "Branch"
 CALL = "Call"
@@ -746,28 +748,30 @@ class PerfStore:
         rows = block.rows if rows is None else np.asarray(rows, np.intp)
         if rows.size == 0:
             return
-        self.ensure_columns(block.n_cols)
-        c = block.n_cols
-        old = int(np.count_nonzero(self._mask[rows]))
-        self._mask[rows] = False
-        self._mask[rows, :c] = block.mask
-        self._count += int(np.count_nonzero(block.mask)) - old
-        self.time[rows] = 0.0
-        self.time[rows, :c] = block.time
-        self.time_var[rows] = 0.0
-        self.time_var[rows, :c] = block.time_var
-        self.samples[rows] = 0
-        self.samples[rows, :c] = block.samples
-        self._dirty[rows] = True
-        for cc in self._counters.values():
-            k = len(cc.vids)
-            cc.values[rows, :k] = 0.0
-            cc.mask[rows, :k] = False
-        for name, (vids, values, mask) in block.counters.items():
-            cc = self._counter_cols(name)
-            slots = np.asarray([cc.slot(v) for v in vids.tolist()], np.intp)
-            cc.values[np.ix_(rows, slots)] = values
-            cc.mask[np.ix_(rows, slots)] = mask
+        with span("store.apply_rows", rows=rows.size):
+            self.ensure_columns(block.n_cols)
+            c = block.n_cols
+            old = int(np.count_nonzero(self._mask[rows]))
+            self._mask[rows] = False
+            self._mask[rows, :c] = block.mask
+            self._count += int(np.count_nonzero(block.mask)) - old
+            self.time[rows] = 0.0
+            self.time[rows, :c] = block.time
+            self.time_var[rows] = 0.0
+            self.time_var[rows, :c] = block.time_var
+            self.samples[rows] = 0
+            self.samples[rows, :c] = block.samples
+            self._dirty[rows] = True
+            for cc in self._counters.values():
+                k = len(cc.vids)
+                cc.values[rows, :k] = 0.0
+                cc.mask[rows, :k] = False
+            for name, (vids, values, mask) in block.counters.items():
+                cc = self._counter_cols(name)
+                slots = np.asarray([cc.slot(v) for v in vids.tolist()],
+                                   np.intp)
+                cc.values[np.ix_(rows, slots)] = values
+                cc.mask[np.ix_(rows, slots)] = mask
 
     # -- whole-store state (the ONE persistence seam) ------------------
     TREE_FORMAT = "perfstore"

@@ -24,6 +24,7 @@ import numpy as np
 from repro.core import psg as psg_lib
 from repro.core.contraction import contract
 from repro.core.graph import COMM, PSG, PerfVector
+from repro.core.spans import span
 
 
 def _block(x):
@@ -38,11 +39,14 @@ class _TimedEval:
     Each value is dropped right after the equation that reads it last:
     run one equation at a time, a train step would otherwise hold every
     intermediate of the step at once (22.6 GB for mamba2-130m at batch 8,
-    seq 4096 — more than a 16 GB chip)."""
+    seq 4096 — more than a 16 GB chip).  Each equation's fence is the
+    span ``profiler.fence``, its stat ``vid`` the equation's entry of
+    ``vids``."""
 
-    def __init__(self, closed_jaxpr):
+    def __init__(self, closed_jaxpr, vids: Sequence[int]):
         from jax._src.core import Literal
         self.closed = closed_jaxpr
+        self.vids = list(vids)
         jaxpr = closed_jaxpr.jaxpr
         last: Dict[Any, int] = {}
         for idx, eqn in enumerate(jaxpr.eqns):
@@ -82,7 +86,8 @@ class _TimedEval:
             subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
             t0 = time.perf_counter()
             ans = eqn.primitive.bind(*subfuns, *invals, **bind_params)
-            _block(ans)
+            with span("profiler.fence", vid=self.vids[idx]):
+                _block(ans)
             on_eqn(idx, time.perf_counter() - t0)
             del invals
             if eqn.primitive.multiple_results:
@@ -115,7 +120,7 @@ class GraphProfiler:
         self._eqn_to_vertex = [self.mapping.get(vid, self.psg.root)
                                for vid in top]
         self._compiled = jax.jit(fn)
-        self._evaluator = _TimedEval(self.closed)
+        self._evaluator = _TimedEval(self.closed, self._eqn_to_vertex)
         # accumulators
         self._vertex_times: Dict[int, List[float]] = {}
         self.step_times: List[float] = []
@@ -128,10 +133,11 @@ class GraphProfiler:
         self.total_steps += 1
         if self.total_steps % self.sample_every == 0:
             return self._sampled_step(*args)
-        t0 = time.perf_counter()
-        out = self._compiled(*args)
-        _block(out)
-        self.step_times.append(time.perf_counter() - t0)
+        with span("profiler.compiled_step"):
+            t0 = time.perf_counter()
+            out = self._compiled(*args)
+            _block(out)
+            self.step_times.append(time.perf_counter() - t0)
         return out
 
     def _sampled_step(self, *args) -> Any:
@@ -142,12 +148,14 @@ class GraphProfiler:
             vid = self._eqn_to_vertex[idx]
             self._vertex_times.setdefault(vid, []).append(dt)
 
-        t0 = time.perf_counter()
-        outs = self._evaluator(flat, on_eqn)
-        self.step_times.append(time.perf_counter() - t0)
-        out_tree = jax.tree.structure(
-            jax.eval_shape(self.fn, *args))
-        return jax.tree.unflatten(out_tree, outs)
+        with span("profiler.sampled_step",
+                  eqns=len(self.closed.jaxpr.eqns)):
+            t0 = time.perf_counter()
+            outs = self._evaluator(flat, on_eqn)
+            self.step_times.append(time.perf_counter() - t0)
+            out_tree = jax.tree.structure(
+                jax.eval_shape(self.fn, *args))
+            return jax.tree.unflatten(out_tree, outs)
 
     # ------------------------------------------------------------------
     def perf_vectors(self) -> Dict[int, PerfVector]:

@@ -11,6 +11,7 @@ from typing import Dict, List, Optional, Sequence
 from repro.core.backtrack import Path, root_causes
 from repro.core.detect import Abnormal, NonScalable
 from repro.core.graph import PPG, PSG
+from repro.core.spans import spanned
 
 
 def _fmt_node(psg: PSG, node) -> str:
@@ -20,6 +21,7 @@ def _fmt_node(psg: PSG, node) -> str:
     return f"[p{proc}] {v.kind}:{v.name}{loc}"
 
 
+@spanned("report.render")
 def render_report(ppg: PPG, non_scalable: Sequence[NonScalable],
                   abnormal: Sequence[Abnormal], paths: Sequence[Path],
                   *, title: str = "ScalAna scaling-loss report",

@@ -34,13 +34,21 @@ from typing import (Any, Dict, Iterator, List, Mapping, Optional, Sequence,
 import numpy as np
 
 from repro.core.graph import PerfStore, PerfVector
+from repro.core.spans import span
 
 
 _jit_row_scatter = None
 
 
+def scatter_rows(buf, rows, vals):
+    """``buf`` with ``vals`` written at ``rows``: a device view's
+    dirty-row upload (jitted, a device trace names it
+    ``jit_scatter_rows``)."""
+    return buf.at[rows].set(vals)
+
+
 def _row_scatter():
-    """Cached jitted ``buf.at[rows].set(vals)``.
+    """Cached jitted :func:`scatter_rows`.
 
     The eager ``at[].set`` path re-runs jax's python scatter lowering on
     every call (~1ms each on CPU); with 8 blocks x (time + var + counter)
@@ -50,8 +58,7 @@ def _row_scatter():
     global _jit_row_scatter
     if _jit_row_scatter is None:
         import jax
-        _jit_row_scatter = jax.jit(
-            lambda buf, rows, vals: buf.at[rows].set(vals))
+        _jit_row_scatter = jax.jit(scatter_rows)
     return _jit_row_scatter
 
 
@@ -242,34 +249,40 @@ class ShardedStore:
     def _cols(self) -> int:
         return max(sh._cols for sh in self.shards)
 
+    # each stacked read is the span ``store.stack``, its stat ``shards``
     def time_matrix(self, n_vertices: Optional[int] = None) -> np.ndarray:
         n = self._cols if n_vertices is None else n_vertices
-        return np.vstack([sh.time_matrix(n) for sh in self.shards])
+        with span("store.stack", shards=len(self.shards)):
+            return np.vstack([sh.time_matrix(n) for sh in self.shards])
 
     def var_matrix(self, n_vertices: Optional[int] = None) -> np.ndarray:
         n = self._cols if n_vertices is None else n_vertices
-        return np.vstack([sh.var_matrix(n) for sh in self.shards])
+        with span("store.stack", shards=len(self.shards)):
+            return np.vstack([sh.var_matrix(n) for sh in self.shards])
 
     def counter_matrix(self, name: str,
                        n_vertices: Optional[int] = None) -> np.ndarray:
         n = self._cols if n_vertices is None else n_vertices
-        return np.vstack([sh.counter_matrix(name, n) for sh in self.shards])
+        with span("store.stack", shards=len(self.shards)):
+            return np.vstack([sh.counter_matrix(name, n)
+                              for sh in self.shards])
 
     def counter_columns(self, name: str
                         ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Stacked compressed view: the union of the shards' written
         columns, each shard's block placed at its row range."""
-        per = [sh.counter_columns(name) for sh in self.shards]
-        vids = np.unique(np.concatenate([v for v, _, _ in per]))
-        values = np.zeros((self.n_procs, vids.size))
-        mask = np.zeros((self.n_procs, vids.size), bool)
-        for sh, (v, val, m) in zip(self.shards, per):
-            if not v.size:
-                continue
-            slots = np.searchsorted(vids, v)
-            values[sh.proc_start:sh.proc_stop, slots] = val
-            mask[sh.proc_start:sh.proc_stop, slots] = m
-        return vids, values, mask
+        with span("store.stack", shards=len(self.shards)):
+            per = [sh.counter_columns(name) for sh in self.shards]
+            vids = np.unique(np.concatenate([v for v, _, _ in per]))
+            values = np.zeros((self.n_procs, vids.size))
+            mask = np.zeros((self.n_procs, vids.size), bool)
+            for sh, (v, val, m) in zip(self.shards, per):
+                if not v.size:
+                    continue
+                slots = np.searchsorted(vids, v)
+                values[sh.proc_start:sh.proc_stop, slots] = val
+                mask[sh.proc_start:sh.proc_stop, slots] = m
+            return vids, values, mask
 
     def time_column(self, vid: int) -> np.ndarray:
         return np.concatenate([sh.time_column(vid) for sh in self.shards])
@@ -412,15 +425,15 @@ class DeviceShardView:
       immutable once their run completes, so their merge runs ONCE and
       the cached column feeds every later detect; any write, re-pin,
       layout or dtype change invalidates it automatically.
-    * ``kernel_launches`` counts detection kernel launches fed from this
-      view (bumped by the ``detect_jax`` entry points), so tests and
-      benches can assert "steady-state detect = <=2 launches" directly.
+    * each :meth:`refresh` is the span ``feed.refresh`` (see
+      :mod:`repro.core.spans`), with the stats ``blocks``,
+      ``dirty_blocks``, ``rows``, ``bytes`` and ``full``.
     """
 
     __slots__ = ("blocks", "_time", "_var", "_counters", "_cols", "_dtype",
                  "last_upload_rows", "last_upload_bytes",
-                 "total_upload_bytes", "refreshes", "full_uploads",
-                 "revision", "kernel_launches", "_merged_cache")
+                 "total_upload_bytes", "full_uploads", "revision",
+                 "_merged_cache")
 
     def __init__(self, store):
         if isinstance(store, ShardedStore):
@@ -438,10 +451,8 @@ class DeviceShardView:
         self.last_upload_rows = 0
         self.last_upload_bytes = 0
         self.total_upload_bytes = 0
-        self.refreshes = 0
         self.full_uploads = 0
         self.revision = 0
-        self.kernel_launches = 0
         self._merged_cache: Optional[tuple] = None
 
     @property
@@ -486,14 +497,6 @@ class DeviceShardView:
 
         from repro.core.detect_jax import precision
         dtype, ctx = precision(dtype)
-        if n_vertices is None:
-            n_vertices = max(b._cols for b in self.blocks)
-        V = int(n_vertices)
-        full = (self._time is None or self._cols != V
-                or self._dtype != dtype
-                or any(buf.shape[0] != b.n_procs
-                       for buf, b in zip(self._time, self.blocks)))
-        rows_up = bytes_up = 0
         # Every upload is STAGED: new buffers build up in local lists and
         # commit — together with the stores' dirty-flag clears — only
         # after every transfer succeeded.  A device upload that raises
@@ -501,7 +504,15 @@ class DeviceShardView:
         # leaves the view's buffers AND the dirty flags untouched, so a
         # retried refresh re-uploads the very rows the failed call lost;
         # clearing eagerly used to drop them forever.
-        with ctx:
+        with span("feed.refresh", blocks=len(self.blocks)) as sp, ctx:
+            if n_vertices is None:
+                n_vertices = max(b._cols for b in self.blocks)
+            V = int(n_vertices)
+            full = (self._time is None or self._cols != V
+                    or self._dtype != dtype
+                    or any(buf.shape[0] != b.n_procs
+                           for buf, b in zip(self._time, self.blocks)))
+            rows_up = bytes_up = dirty = 0
             if full:
                 new_time, new_var, new_counters = [], [], []
                 for b in self.blocks:
@@ -526,6 +537,7 @@ class DeviceShardView:
                 self.full_uploads += 1
                 for b in self.blocks:
                     b.clear_dirty()
+                dirty = len(self.blocks)
             else:
                 new_time = list(self._time)
                 new_var = list(self._var)
@@ -563,13 +575,15 @@ class DeviceShardView:
                 self._counters = new_counters
                 for b in touched:
                     b.clear_dirty()
+                dirty = len(touched)
+            sp.set_metadata(dirty_blocks=dirty, rows=rows_up,
+                            bytes=bytes_up, full=int(full))
         self._cols, self._dtype = V, dtype
         if full or rows_up:
             self.revision += 1
         self.last_upload_rows = rows_up
         self.last_upload_bytes = bytes_up
         self.total_upload_bytes += bytes_up
-        self.refreshes += 1
         return bytes_up
 
     # -- device reads (what the jitted detectors consume) --------------

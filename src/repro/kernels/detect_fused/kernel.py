@@ -311,6 +311,7 @@ def ns_fused_kernel(t: jax.Array, var: jax.Array, hist: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((1, V), dt) for _ in range(6)],
         interpret=interpret,
+        name="detect_non_scalable",
     )(t, var, hist, logp, present, top_mask, params)
 
 
@@ -510,4 +511,5 @@ def ab_fused_kernel(t: jax.Array, valid: jax.Array, top_mask: jax.Array,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_AB_VMEM_LIMIT),
         interpret=interpret,
+        name="detect_abnormal",
     )(t, valid, top_mask, params)

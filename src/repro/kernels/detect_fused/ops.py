@@ -27,16 +27,17 @@ a block tournament extracts the top-k without the 45ms stable argsort —
 while reproducing the reference ranking bit-for-bit (descending score,
 ties by ascending vid-major flat index).
 
-Every op bumps ``launch_counts`` and calls the monkeypatchable
-``on_launch`` hook once per logical kernel launch, so tests and benches
-can ASSERT "steady-state detect = 1 non-scalable + 1 abnormal launch"
-instead of inferring it from timings.
+Every op bumps ``launch_counts`` once per logical kernel launch, so
+tests and benches can ASSERT "steady-state detect = 1 non-scalable + 1
+abnormal launch" instead of inferring it from timings.  On the Pallas
+paths each eager concatenation of a scale's blocks is the span
+``detect.concat`` (:mod:`repro.core.spans`), its stat ``operands``.
 """
 from __future__ import annotations
 
 import collections
 from functools import partial
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,21 +47,18 @@ from repro.kernels.detect_fused.kernel import (
     from_key, key_floor, merge_all_stack, merge_blocks, ns_fused_kernel,
     slope_share_flag, to_key)
 from repro.core.detect import JIT_STRATEGIES
+from repro.core.spans import span
 
 _IMAX = JIT_STRATEGIES.index("max")
 
 # -- launch counting seam ----------------------------------------------
-# One logical launch == one fused op call.  ``launch_counts`` accumulates
-# per-op totals; ``on_launch`` (monkeypatchable) sees each launch name.
+# One logical launch == one fused op call; ``launch_counts`` accumulates
+# per-op totals.
 launch_counts: collections.Counter = collections.Counter()
-on_launch: Optional[Callable[[str], None]] = None
 
 
 def _note_launch(name: str) -> None:
     launch_counts[name] += 1
-    hook = on_launch
-    if hook is not None:
-        hook(name)
 
 
 def reset_launch_counts() -> None:
@@ -190,6 +188,15 @@ def _pad_rows(a: jax.Array, P: int, axis: int) -> jax.Array:
     return jnp.pad(a, pad)                             # zero rows = dead
 
 
+def _rows_of(blocks: Tuple[jax.Array, ...]) -> jax.Array:
+    """The blocks' rows as one (P, V) device array (an eager
+    concatenation where there are several)."""
+    if len(blocks) == 1:
+        return blocks[0]
+    with span("detect.concat", operands=len(blocks)):
+        return jnp.concatenate(blocks, axis=0)
+
+
 def _top_mask(top_idx, V: int, dtype) -> jax.Array:
     m = jnp.zeros((1, _lanes(V)), dtype)
     if top_idx is not None and top_idx.shape[0]:
@@ -240,7 +247,7 @@ def fused_abnormal(ts: Sequence[jax.Array], top_idx: Optional[jax.Array],
             top_idx if top_idx is not None else jnp.zeros((0,), jnp.int32),
             params, k=k_eff, use_step=use_step, use_live=live is not None,
             use_valid=valid is not None)
-    t = ts[0] if len(ts) == 1 else jnp.concatenate(ts, axis=0)
+    t = _rows_of(ts)
     if live is not None:
         t = t[live]
     t = _pad_cols(t, V)
@@ -316,8 +323,7 @@ def fused_non_scalable_live(ts: Sequence[jax.Array],
                              dtype)
         return _ns_live_jnp(ts, vs, hist, logp, present, top_idx, params)
     V = ts[0].shape[1]
-    t = ts[0] if len(ts) == 1 else jnp.concatenate(ts, axis=0)
-    v = vs[0] if len(vs) == 1 else jnp.concatenate(vs, axis=0)
+    t, v = _rows_of(ts), _rows_of(vs)
     P = t.shape[0]
     n_hist = int(hist.shape[1])
     t = _pad_rows(t, P, axis=0)[None]

@@ -28,6 +28,7 @@ from repro.configs import base as cfgbase
 from repro.configs import get as get_config
 from repro.configs import SHAPES
 from repro.core.profiler import GraphProfiler
+from repro.core.spans import span
 from repro.checkpoint import CheckpointManager
 from repro.data import make_dataset
 from repro.distributed.axes import spec_for, use_rules
@@ -233,25 +234,27 @@ class Trainer:
 
         rank = jax.process_index()
         for i in range(start_step, start_step + num_steps):
-            batch = self._put_batch(self.dataset.batch(i))
-            t0 = time.perf_counter()
-            if self.inject_delay.get(rank):
-                time.sleep(self.inject_delay[rank])   # straggler case study
-            state, metrics = step_fn(state, batch)
-            jax.block_until_ready(metrics["loss"])
-            dt = time.perf_counter() - t0
-            self.step_wall_times.append(dt)
-            if step_timeout_s and dt > step_timeout_s:
-                # straggler mitigation: surface instead of hanging the job
-                self.metrics_log.append({"step": i, "timeout": dt})
-            self.metrics_log.append(
-                {"step": i,
-                 "loss": float(metrics["loss"]),
-                 "grad_norm": float(metrics.get("grad_norm", 0.0)),
-                 "wall_s": dt})
-            if (self.ckpt is not None and self.run.checkpoint_every
-                    and (i + 1) % self.run.checkpoint_every == 0):
-                self.ckpt.save(i + 1, jax.device_get(state))
+            with span("trainer.step", step=i):
+                with span("trainer.batch"):
+                    batch = self._put_batch(self.dataset.batch(i))
+                t0 = time.perf_counter()
+                if self.inject_delay.get(rank):    # straggler case study
+                    time.sleep(self.inject_delay[rank])
+                state, metrics = step_fn(state, batch)
+                jax.block_until_ready(metrics["loss"])
+                dt = time.perf_counter() - t0
+                self.step_wall_times.append(dt)
+                if step_timeout_s and dt > step_timeout_s:
+                    # straggler mitigation: surface instead of hanging the job
+                    self.metrics_log.append({"step": i, "timeout": dt})
+                self.metrics_log.append(
+                    {"step": i,
+                     "loss": float(metrics["loss"]),
+                     "grad_norm": float(metrics.get("grad_norm", 0.0)),
+                     "wall_s": dt})
+                if (self.ckpt is not None and self.run.checkpoint_every
+                        and (i + 1) % self.run.checkpoint_every == 0):
+                    self.ckpt.save(i + 1, jax.device_get(state))
         if self.ckpt is not None:
             self.ckpt.save(start_step + num_steps, jax.device_get(state),
                            blocking=True)

@@ -13,8 +13,8 @@ Pins the fused ops (``repro.kernels.detect_fused``) three ways:
   live-set sizes (a flapping host must not retrace).
 * CACHE — historical scales' merged columns stay device-resident across
   detect calls: a steady-state detect with one dirty live scale uploads
-  ONLY the dirty rows and launches <= 2 fused kernels (asserted via the
-  ``on_launch`` seam, not inferred from timings); writes, dtype flips
+  ONLY the dirty rows and launches <= 2 fused kernels (asserted via
+  ``launch_counts``, not inferred from timings); writes, dtype flips
   and layout changes invalidate exactly the affected columns.
 
 Everything here needs jax; the module skips cleanly without it.
@@ -368,16 +368,13 @@ def test_steady_state_detect_dirty_rows_only_and_two_launches():
     rows = np.arange(7, 23)
     live_ppg.perf.set_entries(rows, 2, 0.5)
     ops.reset_launch_counts()
-    seen = []
-    ops.on_launch = seen.append
-    try:
-        ns = detect_non_scalable(series, backend="jax", min_share=0.0)
-        assert live_view.last_upload_rows == rows.size  # dirty rows only
-        ab = detect_abnormal(live_ppg, backend="jax")
-        assert live_view.last_upload_rows == 0     # already clean
-    finally:
-        ops.on_launch = None
-    assert seen == ["non_scalable_live", "abnormal"]   # <= 2 launches
+    ns = detect_non_scalable(series, backend="jax", min_share=0.0)
+    assert live_view.last_upload_rows == rows.size  # dirty rows only
+    assert dict(ops.launch_counts) == {"non_scalable_live": 1}
+    ab = detect_abnormal(live_ppg, backend="jax")
+    assert live_view.last_upload_rows == 0     # already clean
+    assert dict(ops.launch_counts) == {"non_scalable_live": 1,
+                                       "abnormal": 1}   # <= 2 launches
     for v in hist_views:
         assert v.last_upload_rows == 0             # historical: untouched
         assert v.merged_column() is not None
@@ -430,15 +427,17 @@ def test_dtype_flip_invalidates_all_columns(monkeypatch):
 
 
 def test_kernel_launch_counter_on_views():
-    """``view.kernel_launches`` counts detection launches fed from each
-    view — cache fills on historical scales, every detect on the live
-    one."""
+    """``launch_counts`` over three detect cycles: one cache fill per
+    historical scale in all, one non-scalable and one abnormal launch
+    on the live scale every cycle."""
     _, series = _sharded_series(scales=(4, 8, 16))
     scales = sorted(series)
+    ops.reset_launch_counts()
     for _ in range(3):
         detect_non_scalable(series, backend="jax", min_share=0.0)
         detect_abnormal(series[scales[-1]], backend="jax")
-    for n in scales[:-1]:
-        assert series[n].device_view().kernel_launches == 1  # one merge
-    # live scale: one ns + one ab launch per detect cycle
-    assert series[scales[-1]].device_view().kernel_launches == 6
+    assert dict(ops.launch_counts) == {"merge_column": len(scales) - 1,
+                                       "non_scalable_live": 3,
+                                       "abnormal": 3}
+    for n in scales[:-1]:                   # each filled its own column
+        assert series[n].device_view().merged_column() is not None

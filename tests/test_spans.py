@@ -1,0 +1,189 @@
+"""The program's spans (``repro.core.spans``).
+
+A real ``jax.profiler`` trace on the CPU around one smoke-size diagnosis
+cycle (detection kernels in Pallas interpret mode, the code path a chip
+runs) and one sampled plus one compiled train step: every name in
+``NAMES`` is emitted, nested as the layers nest, with its stats.  The
+names stay clear of the chip benchmark's own spans, and the analysis
+layer keeps importing and running without jax."""
+import collections
+import importlib.util
+import os
+import subprocess
+import sys
+import textwrap
+
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from repro.core import spans
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# span -> the spans one of which must hold it (None: none may)
+PARENTS = {
+    "trainer.step": None,
+    "trainer.batch": {"trainer.step"},
+    "profiler.compiled_step": {"trainer.step"},
+    "profiler.sampled_step": {"trainer.step"},
+    "profiler.fence": {"profiler.sampled_step"},
+    "store.apply_rows": None,
+    "detect.non_scalable": None,
+    "detect.abnormal": None,
+    "feed.refresh": {"detect.non_scalable", "detect.abnormal"},
+    "detect.concat": {"detect.non_scalable", "detect.abnormal"},
+    "detect.readback": {"detect.non_scalable", "detect.abnormal"},
+    "backtrack": None,
+    "root_causes": {None, "report.render"},
+    "store.stack": {"backtrack", "root_causes", "report.render"},
+    "report.render": None,
+}
+STATS = {
+    "trainer.step": {"step"},
+    "profiler.sampled_step": {"eqns"},
+    "profiler.fence": {"vid"},
+    "store.apply_rows": {"rows"},
+    "feed.refresh": {"blocks", "dirty_blocks", "rows", "bytes", "full"},
+    "detect.concat": {"operands"},
+    "store.stack": {"shards"},
+}
+
+
+def _cycle_inputs():
+    from repro.core.inject import simulate
+    from tests.test_device_detect import _step_psg
+    g = _step_psg(32)
+
+    def base(proc, v, n):
+        return 0.01 * (1 + proc % 3) + 0.001 * v + 0.02 / n \
+            + (0.06 if (proc, v) == (3, 2) else 0.0)
+
+    series = {n: simulate(g, n, lambda p, v, n=n: base(p, v, n),
+                          shards=4).ppg for n in (8, 16, 32)}
+    return g, series
+
+
+def _diagnose(series):
+    from repro.core import (backtrack, detect_abnormal, detect_non_scalable,
+                            render_report, root_causes)
+    live = series[max(series)]
+    shard = live.perf.shards[0]
+    shard.apply_rows(shard.extract_rows([0, 1]))
+    ns = detect_non_scalable(series, backend="jax", min_share=0.0)
+    ab = detect_abnormal(live, backend="jax")
+    paths = backtrack(live, ns, ab)
+    root_causes(paths, live.psg, ppg=live)
+    return render_report(live, ns, ab, paths)
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    from jax.profiler import ProfileData
+    from repro.configs import get_smoke
+    from repro.configs.base import RunConfig, ShapeConfig
+    from repro.kernels.detect_fused import ops
+    from repro.training import Trainer
+
+    cfg = get_smoke("mamba2-130m")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "kernel_mode", lambda interpret=None: "interpret")
+        mp.setenv("SCALANA_DETECT_F32", "1")
+        tr = Trainer(RunConfig(arch=cfg.name, total_steps=4, warmup_steps=1,
+                               scalana_sample_every=2),
+                     arch_cfg=cfg, shape=ShapeConfig("spans", 16, 2, "train"))
+        state = tr.train(num_steps=1)             # compiled, outside
+        _, series = _cycle_inputs()
+        _diagnose(series)                          # compiles, full upload
+        out = str(tmp_path_factory.mktemp("trace"))
+        with jax.profiler.trace(out):
+            tr.train(num_steps=2, state=state)     # sampled, then compiled
+            report = _diagnose(series)
+    assert "Root causes" in report
+    path = next(os.path.join(d, f) for d, _, fs in os.walk(out)
+                for f in fs if f.endswith(".xplane.pb"))
+    lines = collections.defaultdict(list)
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("scalana."):
+                    lines[(plane.name, line.name)].append(
+                        (ev.start_ns, ev.end_ns, ev.name[len("scalana."):],
+                         {k for k, _ in ev.stats}))
+    return lines
+
+
+def _events(recorded):
+    return [ev for evs in recorded.values() for ev in evs]
+
+
+def test_every_name_is_emitted_with_its_stats(recorded):
+    seen = collections.defaultdict(set)
+    for _, _, name, stats in _events(recorded):
+        seen[name] |= stats
+    assert set(seen) == set(spans.NAMES) == set(PARENTS)
+    for name, want in STATS.items():
+        assert want <= seen[name], (name, seen[name])
+
+
+def test_spans_nest_as_the_layers_do(recorded):
+    for evs in recorded.values():
+        for s, e, name, _ in evs:
+            holders = {n for s2, e2, n, _ in evs
+                       if (s2, e2, n) != (s, e, name) and s2 <= s and e <= e2}
+            allowed = PARENTS[name]
+            if allowed is None:
+                assert not holders, (name, holders)
+            elif None in allowed:
+                assert holders <= allowed, (name, holders)
+            else:
+                assert holders & allowed, (name, holders)
+
+
+def test_names_differ_from_the_benchmark_spans():
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_harness", os.path.join(REPO, "chipbench", "harness.py"))
+    harness = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(harness)
+    emitted = {"scalana." + n for n in spans.NAMES}
+    assert not emitted & set(harness.SPANS)
+    assert len(set(spans.NAMES)) == len(spans.NAMES)
+
+
+def test_core_imports_and_spans_run_without_jax():
+    code = textwrap.dedent("""
+        import importlib.abc
+        import sys
+
+        class NoJax(importlib.abc.MetaPathFinder):
+            def find_spec(self, name, path, target=None):
+                if name.split(".")[0] in ("jax", "jaxlib"):
+                    raise ImportError(f"{name} is not installed here")
+
+        sys.meta_path.insert(0, NoJax())
+        import repro.core
+        from repro.core import (backtrack, detect_abnormal,
+                                detect_non_scalable, render_report)
+        from repro.core.inject import simulate_series
+        from repro.core.spans import span
+        with span("feed.refresh", blocks=3) as sp:
+            sp.set_metadata(rows=2)
+        from repro.core import PSG, COMP
+        g = PSG()
+        g.root = g.new_vertex("Root", "root").vid
+        for i in range(3):
+            v = g.new_vertex(COMP, f"c{i}", parent=g.root)
+            g.add_edge(g.root, v.vid, "control")
+        series = simulate_series(g, [2, 4],
+                                 lambda p, vid, n: 1.0 / n + (vid == 1))
+        ns = detect_non_scalable(series)
+        ab = detect_abnormal(series[4])
+        render_report(series[4], ns, ab, backtrack(series[4], ns, ab))
+        assert "jax" not in sys.modules
+        print("jax-free-ok")
+    """)
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert "jax-free-ok" in out.stdout

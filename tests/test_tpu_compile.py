@@ -81,3 +81,35 @@ def test_ab_kernel_compiles_for_v5e(one_chip, P, V):
     text = _compile(lambda *a: ab_fused_kernel(*a, k=20),
                     [(P, V), (P, 1), (1, V), (1, 8)], one_chip)
     assert "tpu_custom_call" in text
+
+
+def _lowered(fn, shapes, sharding, dtypes=None, **static):
+    dtypes = dtypes or [jnp.float32] * len(shapes)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in zip(shapes, dtypes)]
+    return fn.lower(*args, **static).as_text()
+
+
+@pytest.mark.parametrize("program", ["scatter", "non_scalable", "abnormal"])
+def test_detection_programs_have_stable_names(one_chip, program):
+    """A device trace names an operation by its program's module, and a
+    Pallas kernel's operation by the kernel's name: the device feed's
+    row scatter and both fused kernels carry names of their own."""
+    from repro.core.shard import _row_scatter
+    P, V = 512, 128
+    if program == "scatter":
+        text = _lowered(_row_scatter(), [(P, V), (8,), (8, V)], one_chip,
+                        [jnp.float32, jnp.int32, jnp.float32])
+        module, kernel = "jit_scatter_rows", None
+    elif program == "non_scalable":
+        text = _lowered(ns_fused_kernel,
+                        [(3, P, V), (3, P, V), (4, 1, V), (3, 1), (3, V),
+                         (1, V), (1, 8)], one_chip, n_hist=0)
+        module, kernel = "jit_ns_fused_kernel", "detect_non_scalable"
+    else:
+        text = _lowered(ab_fused_kernel, [(P, V), (P, 1), (1, V), (1, 8)],
+                        one_chip, k=20)
+        module, kernel = "jit_ab_fused_kernel", "detect_abnormal"
+    assert text.splitlines()[0].startswith(f"module @{module} ")
+    if kernel is not None:
+        assert f'kernel_name = "{kernel}"' in text
