@@ -302,10 +302,11 @@ def detect_non_scalable(series: Mapping[int, PPG], *,
     jx = (_resolve_backend(backend, device_live=device_ok)
           if strategy in JIT_STRATEGIES else None)
     if jx is not None and device_ok:
-        # device-fed: each scale's per-host blocks feed the kernels from
-        # its cached DeviceShardView (dirty rows re-upload, nothing
-        # else); neither the stacked (S, Pmax, V) tensor nor the sharded
-        # reference's (P, V) matrix is ever assembled on the host, and
+        # device-fed: each scale's rows feed the kernels from its cached
+        # DeviceShardView's resident buffers (dirty rows re-upload,
+        # nothing else); neither the stacked (S, Pmax, V) tensor nor the
+        # sharded reference's (P, V) matrix is ever assembled on the
+        # host, and
         # the total step time reduces blockwise on the device
         for si, p in enumerate(scales):
             vp = min(len(series[p].psg.vertices), V)
@@ -416,8 +417,8 @@ def detect_abnormal(ppg: PPG, *, abnorm_thd: float = 1.3,
     device_ok = isinstance(ppg.perf, ShardedStore)
     jx = _resolve_backend(backend, device_live=device_ok)
     if jx is not None and device_ok:
-        # device-fed: the per-host blocks live on the device (dirty rows
-        # re-upload per call), concatenate there, and the step time,
+        # device-fed: the rows live on the device as one resident (P, V)
+        # buffer (dirty rows re-upload per call), and the step time,
         # median, flagging and ranking all run device-side — the stacked
         # (P, V) host matrix is never materialized
         vids, procs, typical, _ = jx.abnormal_topk_view(

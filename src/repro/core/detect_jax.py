@@ -16,13 +16,14 @@ host.  Three kernels cover the pipeline:
   top-k, all device-side; only the winners and the (V,) typical
   transfer.  (``_abnormal_kernel`` keeps the host-median parity entry.)
 
-A second kernel family consumes per-host DEVICE blocks instead of one
+A second kernel family consumes DEVICE row blocks instead of one
 host-stacked matrix (:class:`~repro.core.shard.DeviceShardView` inputs —
-the online path, where only dirty rows re-upload per call):
+the online path, where only dirty rows re-upload per call, and the view
+hands one resident (P, V) buffer):
 ``_merge_blocks_kernel`` computes each scale's merge column as
 block-level reductions, ``_slope_flag_from_M_kernel`` derives the total
 step time from the merged stack itself, and
-``_abnormal_topk_blocks_kernel`` concatenates blocks on the device.
+``_abnormal_topk_blocks_kernel`` concatenates any blocks on the device.
 ``non_scalable_views`` / ``abnormal_topk_view`` are their entry points;
 the stacked (P, V) matrix exists on neither host nor wire.
 
@@ -97,7 +98,7 @@ def _non_scalable_kernel(t, var, logp, present, total_max,
 
 
 # -- device-block kernels (DeviceShardView inputs) ------------------
-# One scale's per-host blocks -> its (4, V) merged column, as
+# One scale's row blocks -> its (4, V) merged column, as
 # associative block-level reductions (row order = global proc order;
 # the stacked host matrix never exists on either side).
 _merge_blocks_kernel = jax.jit(_merge_blocks)
@@ -342,9 +343,9 @@ def abnormal_topk_view(view, n_vertices: int, top: Sequence[int],
     :class:`~repro.core.shard.DeviceShardView` — the online entry point.
 
     ``view.refresh`` uploads only the rows written since the last call
-    (O(dirty rows), not O(P·V)); the per-host blocks then concatenate on
-    the device, where the step time, median, flagging and top-k ranking
-    all run.  The host never materializes the stacked (P, V) matrix.
+    (O(dirty rows), not O(P·V)) into the view's resident (P, V) buffer,
+    where the step time, median, flagging and top-k ranking all run.
+    The host never materializes the stacked (P, V) matrix.
     ``top`` is the root's child vids (the step-time columns).  Returns
     ``(vids, procs, typical, n_flagged)`` like :func:`abnormal_topk`.
 

@@ -1278,7 +1278,7 @@ class PPG:
 
     def device_view(self):
         """This PPG's cached :class:`~repro.core.shard.DeviceShardView` —
-        the perf store's per-host blocks pinned as jax device buffers with
+        the perf store's rows pinned as resident jax device buffers with
         dirty-row incremental upload.  Created lazily (jax imports happen
         on first refresh, never here); the jitted detectors feed from it
         so a ShardedStore-backed PPG never materializes the stacked

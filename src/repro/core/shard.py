@@ -21,7 +21,8 @@ straight into a ShardedStore (multi-host replay), and
 ``build_ppg`` unchanged.
 
 :class:`DeviceShardView` closes the online-detection loop: it pins the
-per-host blocks as jax device buffers with dirty-row incremental upload,
+per-host blocks' rows as one resident (P, V) jax device buffer per matrix
+with dirty-row incremental upload,
 so the jitted detectors consume device-resident inputs instead of a
 re-stacked, re-transferred host matrix on every call.  This module itself
 never imports jax (the view imports it lazily inside ``refresh``).
@@ -51,15 +52,27 @@ def _row_scatter():
     """Cached jitted :func:`scatter_rows`.
 
     The eager ``at[].set`` path re-runs jax's python scatter lowering on
-    every call (~1ms each on CPU); with 8 blocks x (time + var + counter)
-    buffers per refresh that dominated the steady-state detect cycle.
-    One jitted helper turns each upload into a cached-executable dispatch.
+    every call (~1ms each on CPU); one jitted helper turns each upload
+    into a cached-executable dispatch.
     """
     global _jit_row_scatter
     if _jit_row_scatter is None:
         import jax
         _jit_row_scatter = jax.jit(scatter_rows)
     return _jit_row_scatter
+
+
+def _pad_pow2(rows: np.ndarray, *slabs: np.ndarray) -> tuple:
+    """``rows`` and its ``slabs`` padded up to a power-of-two length by
+    repeating the last (row, values) pair: a scatter writes that row
+    again with the same values, and varying dirty-row counts share one
+    compiled scatter per power of two."""
+    n = rows.size
+    m = 1 << (n - 1).bit_length()
+    if m == n:
+        return (rows,) + slabs
+    idx = np.minimum(np.arange(m), n - 1)
+    return (rows[idx],) + tuple(s[idx] for s in slabs)
 
 
 def shard_ranges(n_procs: int, n_hosts: int) -> List[Tuple[int, int]]:
@@ -384,13 +397,15 @@ class ShardedStore:
 
 
 class DeviceShardView:
-    """Per-host perf blocks pinned as jax device buffers, incrementally.
+    """A store's (P, V) time and variance pinned as device buffers,
+    incrementally.
 
     The missing half of online detection: :class:`ShardedStore` keeps the
     (P, V) time matrix as per-host blocks on the HOST, and every jitted
     detect call used to re-assemble and re-transfer the whole stacked
-    matrix.  A view pins each block — time, time-variance, and the
-    column-sparse counter blocks — as device buffers once, then
+    matrix.  A view pins the time and time-variance matrices as ONE
+    resident (P, V) device buffer each, the blocks' rows in block order,
+    plus each block's column-sparse counter blocks, once; then
     :meth:`refresh` re-uploads only the rows written since the last
     refresh (the store's dirty-row tracking, see
     :meth:`~repro.core.graph.PerfStore.dirty_rows`), so the steady-state
@@ -400,21 +415,27 @@ class DeviceShardView:
 
     * construction stores only host references — no jax import, no
       transfer (the analysis layer stays importable without jax);
-    * the first :meth:`refresh` uploads every block in full and clears
-      the dirty flags;
-    * subsequent refreshes upload ``store.dirty_rows()`` per block via an
-      on-device row scatter (``buf.at[rows].set``); a changed column
-      count, row count, dtype, or counter layout re-pins the affected
-      buffers in full;
-    * ``time_blocks()`` / ``var_blocks()`` hand the jitted detectors the
-      per-block device arrays — the detection kernels reduce them
-      blockwise, and only (V,)-sized results ever come back to the host.
+    * the first :meth:`refresh` builds each (P, V) host slab from the
+      blocks in block order, uploads it in one transfer per matrix,
+      records the blocks' row offsets, and clears the dirty flags;
+    * later refreshes gather every block's dirty rows into one slab,
+      placed at global rows (block offset + local row), and write them
+      with ONE row scatter per matrix (``buf.at[rows].set``).  The row
+      count is padded up to a power of two by repeating the last
+      (row, values) pair, so varying dirty counts reuse a few compiled
+      scatters;
+    * the layout is the tuple of block row counts: a block whose row
+      count changes, a changed column count or dtype re-pins the whole
+      matrices in full (a changed counter layout re-pins that counter);
+    * ``time_blocks()`` / ``var_blocks()`` hand the jitted detectors a
+      one-element list holding the resident buffer, and only (V,)-sized
+      results ever come back to the host.
 
     One view per store: refresh consumes the store's dirty flags, so two
     views over the same store would starve each other (``PPG.device_view``
     caches exactly one).  Transfer accounting (``last_upload_rows`` /
-    ``last_upload_bytes`` / ``total_upload_bytes``) is asserted by
-    ``bench_graph_scale`` to scale with dirty rows.
+    ``last_upload_bytes`` / ``total_upload_bytes``, the unpadded slabs)
+    is asserted by ``bench_graph_scale`` to scale with dirty rows.
 
     Two seams serve the fused detectors (``repro.kernels.detect_fused``):
 
@@ -427,13 +448,15 @@ class DeviceShardView:
       layout or dtype change invalidates it automatically.
     * each :meth:`refresh` is the span ``feed.refresh`` (see
       :mod:`repro.core.spans`), with the stats ``blocks``,
-      ``dirty_blocks``, ``rows``, ``bytes`` and ``full``.
+      ``dirty_blocks``, ``rows``, ``bytes``, ``full`` and ``scatters``
+      (the resident matrices' row-scatter dispatches: 2 where rows
+      changed, else 0; counter blocks scatter per block, uncounted).
     """
 
     __slots__ = ("blocks", "_time", "_var", "_counters", "_cols", "_dtype",
-                 "last_upload_rows", "last_upload_bytes",
-                 "total_upload_bytes", "full_uploads", "revision",
-                 "_merged_cache")
+                 "_layout", "last_upload_rows",
+                 "last_upload_bytes", "total_upload_bytes", "full_uploads",
+                 "revision", "_merged_cache")
 
     def __init__(self, store):
         if isinstance(store, ShardedStore):
@@ -443,11 +466,12 @@ class DeviceShardView:
         else:
             raise TypeError(f"DeviceShardView needs a PerfStore or "
                             f"ShardedStore: {type(store).__name__}")
-        self._time: Optional[list] = None      # per-block device buffers
-        self._var: Optional[list] = None
+        self._time = None                      # resident (P, V) buffers
+        self._var = None
         self._counters: Optional[list] = None  # per-block {name: (vids, buf)}
         self._cols = -1
         self._dtype: Optional[np.dtype] = None
+        self._layout: Tuple[int, ...] = ()     # block row counts when pinned
         self.last_upload_rows = 0
         self.last_upload_bytes = 0
         self.total_upload_bytes = 0
@@ -497,7 +521,7 @@ class DeviceShardView:
 
         from repro.core.detect_jax import precision
         dtype, ctx = precision(dtype)
-        # Every upload is STAGED: new buffers build up in local lists and
+        # Every upload is STAGED: new buffers build up in locals and
         # commit — together with the stores' dirty-flag clears — only
         # after every transfer succeeded.  A device upload that raises
         # mid-refresh (OOM, backend error inside ``at[].set``) therefore
@@ -508,21 +532,22 @@ class DeviceShardView:
             if n_vertices is None:
                 n_vertices = max(b._cols for b in self.blocks)
             V = int(n_vertices)
+            layout = tuple(b.n_procs for b in self.blocks)
             full = (self._time is None or self._cols != V
-                    or self._dtype != dtype
-                    or any(buf.shape[0] != b.n_procs
-                           for buf, b in zip(self._time, self.blocks)))
-            rows_up = bytes_up = dirty = 0
+                    or self._dtype != dtype or self._layout != layout)
+            rows_up = bytes_up = scatters = 0
             if full:
-                new_time, new_var, new_counters = [], [], []
+                t = np.concatenate([self._rows_slab(
+                    b.time, np.arange(b.n_procs), V, dtype)
+                    for b in self.blocks])
+                v = np.concatenate([self._rows_slab(
+                    b.time_var, np.arange(b.n_procs), V, dtype)
+                    for b in self.blocks])
+                new_time, new_var = jnp.asarray(t), jnp.asarray(v)
+                rows_up = t.shape[0]
+                bytes_up = t.nbytes + v.nbytes
+                new_counters = []
                 for b in self.blocks:
-                    every = np.arange(b.n_procs)
-                    t = self._rows_slab(b.time, every, V, dtype)
-                    v = self._rows_slab(b.time_var, every, V, dtype)
-                    new_time.append(jnp.asarray(t))
-                    new_var.append(jnp.asarray(v))
-                    rows_up += b.n_procs
-                    bytes_up += t.nbytes + v.nbytes
                     pinned = {}
                     for name in b.counter_names():
                         vids, values, mask = b.counter_columns(name)
@@ -532,30 +557,23 @@ class DeviceShardView:
                                         jnp.asarray(slab))
                         bytes_up += slab.nbytes
                     new_counters.append(pinned)
-                self._time, self._var = new_time, new_var
-                self._counters = new_counters
-                self.full_uploads += 1
-                for b in self.blocks:
-                    b.clear_dirty()
-                dirty = len(self.blocks)
+                touched = self.blocks
             else:
-                new_time = list(self._time)
-                new_var = list(self._var)
-                new_counters = [dict(p) for p in self._counters]
-                touched = []
+                offsets = np.cumsum((0,) + layout[:-1])   # first rows
+                new_time, new_var = self._time, self._var
+                new_counters = list(self._counters)
+                touched, g_rows, t_slabs, v_slabs = [], [], [], []
+                scatter = _row_scatter()
                 for i, b in enumerate(self.blocks):
                     rows = b.dirty_rows()
                     if not rows.size:
                         continue
                     touched.append(b)
-                    scatter = _row_scatter()
-                    t = self._rows_slab(b.time, rows, V, dtype)
-                    v = self._rows_slab(b.time_var, rows, V, dtype)
-                    new_time[i] = scatter(new_time[i], rows, t)
-                    new_var[i] = scatter(new_var[i], rows, v)
-                    rows_up += rows.size
-                    bytes_up += t.nbytes + v.nbytes
-                    pinned = new_counters[i]
+                    t_slabs.append(self._rows_slab(b.time, rows, V, dtype))
+                    v_slabs.append(self._rows_slab(b.time_var, rows, V,
+                                                   dtype))
+                    g_rows.append(rows + offsets[i])
+                    pinned = new_counters[i] = dict(new_counters[i])
                     for name in b.counter_names():
                         vids, values, mask = b.counter_columns(name)
                         key = tuple(vids.tolist())
@@ -571,13 +589,25 @@ class DeviceShardView:
                                 np.where(mask, values, 0.0), dtype)
                             pinned[name] = (key, jnp.asarray(slab))
                         bytes_up += slab.nbytes
-                self._time, self._var = new_time, new_var
-                self._counters = new_counters
-                for b in touched:
-                    b.clear_dirty()
-                dirty = len(touched)
-            sp.set_metadata(dirty_blocks=dirty, rows=rows_up,
-                            bytes=bytes_up, full=int(full))
+                if touched:
+                    rows = np.concatenate(g_rows)
+                    t, v = np.concatenate(t_slabs), np.concatenate(v_slabs)
+                    rows_up = rows.size
+                    bytes_up += t.nbytes + v.nbytes
+                    rows, t, v = _pad_pow2(rows.astype(np.int32), t, v)
+                    new_time = scatter(new_time, rows, t)
+                    new_var = scatter(new_var, rows, v)
+                    scatters = 2
+            self._time, self._var = new_time, new_var
+            self._counters = new_counters
+            self._layout = layout
+            if full:
+                self.full_uploads += 1
+            for b in touched:
+                b.clear_dirty()
+            sp.set_metadata(dirty_blocks=len(touched), rows=rows_up,
+                            bytes=bytes_up, full=int(full),
+                            scatters=scatters)
         self._cols, self._dtype = V, dtype
         if full or rows_up:
             self.revision += 1
@@ -588,15 +618,16 @@ class DeviceShardView:
 
     # -- device reads (what the jitted detectors consume) --------------
     def time_blocks(self) -> list:
-        """Per-block (n_local, V) device time matrices, in row order."""
+        """The resident (P, V) device time matrix, as a one-element list
+        (the fused ops take a sequence of row blocks)."""
         if self._time is None:
             raise RuntimeError("DeviceShardView.refresh() before reading")
-        return list(self._time)
+        return [self._time]
 
     def var_blocks(self) -> list:
         if self._var is None:
             raise RuntimeError("DeviceShardView.refresh() before reading")
-        return list(self._var)
+        return [self._var]
 
     def merged_column(self):
         """The cached (4, V) merged column, or None if stale/absent.

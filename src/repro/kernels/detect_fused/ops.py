@@ -30,7 +30,8 @@ ties by ascending vid-major flat index).
 Every op bumps ``launch_counts`` once per logical kernel launch, so
 tests and benches can ASSERT "steady-state detect = 1 non-scalable + 1
 abnormal launch" instead of inferring it from timings.  On the Pallas
-paths each eager concatenation of a scale's blocks is the span
+paths an eager concatenation of several row blocks (a caller's own; a
+``DeviceShardView`` hands one resident buffer) is the span
 ``detect.concat`` (:mod:`repro.core.spans`), its stat ``operands``.
 """
 from __future__ import annotations
@@ -189,8 +190,9 @@ def _pad_rows(a: jax.Array, P: int, axis: int) -> jax.Array:
 
 
 def _rows_of(blocks: Tuple[jax.Array, ...]) -> jax.Array:
-    """The blocks' rows as one (P, V) device array (an eager
-    concatenation where there are several)."""
+    """The blocks' rows as one (P, V) device array: the block itself
+    where there is one (a device view's resident buffer), an eager
+    concatenation where there are several."""
     if len(blocks) == 1:
         return blocks[0]
     with span("detect.concat", operands=len(blocks)):

@@ -2,15 +2,16 @@
 
 Pins the tentpole to the host-fed semantics:
 
-* device-fed detection (per-host blocks pinned as device buffers,
-  blockwise merge/median/top-k kernels) must pick exactly the same
-  vertices as the host-fed jitted path and the numpy reference — f64
-  results bitwise where the math is order-independent (max merge, median,
+* device-fed detection (the blocks' rows pinned as one resident device
+  buffer per matrix, blockwise merge/median/top-k kernels) must pick
+  exactly the same vertices as the host-fed jitted path and the numpy
+  reference — f64 results bitwise where the math is order-independent (max merge, median,
   winner sets), ~1e-12 for blockwise-reassociated sums, ~1e-4 under
   ``SCALANA_DETECT_F32``;
 * the incremental upload must transfer exactly the rows written since
-  the previous detect call, and the device buffers must equal the host
-  blocks after every refresh — interleaved writes/detects included;
+  the previous detect call, in one row scatter per matrix, and the
+  device buffers must equal the stacked host blocks after every refresh
+  — interleaved writes/detects included;
 * a ShardedStore-backed PPG must run detection WITHOUT materializing the
   stacked host matrix (asserted by making the stacked views explode);
 * regression: an all-dead final scale (``total_max <= 0``) yields share
@@ -178,12 +179,17 @@ def test_device_path_never_stacks_host_matrix(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def _assert_buffers_match(view, V):
-    """Every device buffer equals its host block (padded to V columns)."""
+    """The resident time/var buffers equal the host blocks stacked in
+    block order (padded to V columns); each counter block equals its
+    host block."""
+    assert len(view.time_blocks()) == len(view.var_blocks()) == 1
+    np.testing.assert_array_equal(
+        np.asarray(view.time_blocks()[0]),
+        np.vstack([blk.time_matrix(V) for blk in view.blocks]))
+    np.testing.assert_array_equal(
+        np.asarray(view.var_blocks()[0]),
+        np.vstack([blk.var_matrix(V) for blk in view.blocks]))
     for i, blk in enumerate(view.blocks):
-        np.testing.assert_array_equal(np.asarray(view.time_blocks()[i]),
-                                      blk.time_matrix(V))
-        np.testing.assert_array_equal(np.asarray(view.var_blocks()[i]),
-                                      blk.var_matrix(V))
         for name in blk.counter_names():
             vids, values, mask = blk.counter_columns(name)
             key, buf = view.counter_blocks(name)[i]
@@ -245,6 +251,148 @@ def test_device_view_single_store_and_errors():
     assert view.row_ranges() == [(0, 6)]
     with pytest.raises(TypeError):
         DeviceShardView({})
+
+
+class _Span:
+    def __init__(self, stats):
+        self.stats = stats
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **more):
+        self.stats.update(more)
+
+
+class _SpanLog:
+    """Stand-in for ``repro.core.spans.span``: records each span's name
+    and stats (the ones passed in and those ``set_metadata`` adds)."""
+
+    def __init__(self):
+        self.spans = []
+
+    def __call__(self, name, **stats):
+        self.spans.append((name, stats))
+        return _Span(stats)
+
+    def stats(self, name):
+        return [st for n, st in self.spans if n == name]
+
+
+def _uneven_ranges(n_shards):
+    sizes = [1 + i % 4 for i in range(n_shards)]          # 1, 2, 3, 4, ...
+    stops = np.cumsum(sizes).tolist()
+    return list(zip([0] + stops[:-1], stops))
+
+
+def test_many_uneven_shards_feed_one_resident_buffer(monkeypatch):
+    """36 shards of 1-4 rows: the view hands detection ONE (P, V) buffer
+    per matrix, a refresh after writes to several blocks issues one row
+    scatter per matrix, and detection still equals the numpy
+    reference, with and without a process mask."""
+    pytest.importorskip("jax")
+    import repro.core.shard as shard_mod
+
+    ranges = _uneven_ranges(36)
+    P = ranges[-1][1]
+    g = _step_psg(P)
+    V = len(g.vertices)
+    inject = {(7, 2): 0.5}
+
+    def t_at(p, vid, n):
+        return _base(p, vid) * (0.5 if vid == 3 else P / n)
+
+    series_sh, series_plain = {}, {}
+    for n, sh in ((P // 2, 9), (P, ranges)):
+        f = (lambda p, v, n=n: t_at(p, v, n))
+        series_sh[n] = simulate(g, n, f, inject=inject, shards=sh).ppg
+        series_plain[n] = simulate(g, n, f, inject=inject).ppg
+    live, ref = series_sh[P], series_plain[P]
+    assert len(live.perf.shards) == 36
+
+    view = live.device_view()
+    view.refresh(V)
+    assert len(view.time_blocks()) == len(view.var_blocks()) == 1
+    assert view.time_blocks()[0].shape == (P, V)
+    _assert_buffers_match(view, V)
+
+    # interleaved writes to several blocks, mirrored on the plain store
+    for store in (live.perf, ref.perf):
+        store.set_entries([0, 9, 40, 71], 2, 0.03)
+        store.set_entry(55, 4, 0.02, accumulate=True)
+        store.set_entries([10, 11], 5, 0.04)
+    spans = _SpanLog()
+    monkeypatch.setattr(shard_mod, "span", spans)
+    view.refresh(V)
+    view.refresh(V)                                      # clean
+    monkeypatch.undo()
+    first, clean = spans.stats("feed.refresh")
+    assert first["scatters"] == 2 and first["rows"] == 7
+    assert first["dirty_blocks"] == len(
+        {int(np.searchsorted([lo for lo, _ in ranges], r, "right"))
+         for r in (0, 9, 40, 71, 55, 10, 11)})
+    assert clean["scatters"] == 0 and clean["rows"] == 0
+    _assert_buffers_match(view, V)
+
+    assert _ab_key(detect_abnormal(live, backend="jax")) == \
+        _ab_key(detect_abnormal(ref, backend="numpy"))
+    mask = np.ones(P, bool)
+    mask[[3, 7, 40, P - 1]] = False
+    assert _ab_key(detect_abnormal(live, backend="jax", proc_mask=mask)) \
+        == _ab_key(detect_abnormal(ref, backend="numpy", proc_mask=mask))
+    ns_dev = detect_non_scalable(series_sh, backend="jax", min_share=0.0)
+    ns_np = detect_non_scalable(series_plain, backend="numpy",
+                                min_share=0.0)
+    assert ns_dev and [d.vid for d in ns_dev] == [d.vid for d in ns_np]
+    for a, b in zip(ns_np, ns_dev):
+        assert abs(a.slope - b.slope) <= 1e-12 * max(abs(a.slope), 1)
+        assert abs(a.share - b.share) <= 1e-12 * max(abs(a.share), 1)
+
+
+def test_dirty_counts_share_power_of_two_scatters_and_repin():
+    """Dirty-row counts 1, 3, 5 and 32 leave the buffers exact and
+    compile at most one scatter per power-of-two bucket; counts in the
+    same buckets compile nothing more.  A block whose row count changes
+    re-pins the whole matrix."""
+    pytest.importorskip("jax")
+    from repro.core.shard import _row_scatter
+
+    ranges = _uneven_ranges(40)
+    P, V = ranges[-1][1], 7
+    store = ShardedStore(ranges, V)
+    rng = np.random.default_rng(5)
+    for vid in range(V):
+        store.set_column(vid, rng.random(P))
+    view = DeviceShardView(store)
+    view.refresh(V)
+    scatter = _row_scatter()
+
+    def write(k):
+        rows = rng.choice(P, k, replace=False)
+        store.set_entries(rows, int(rng.integers(V)), rng.random(k))
+        view.refresh(V)
+        assert view.last_upload_rows == k and view.full_uploads == 1
+        _assert_buffers_match(view, V)
+
+    before = scatter._cache_size()
+    for k in (1, 3, 5, 32):                          # buckets 1, 4, 8, 32
+        write(k)
+    grown = scatter._cache_size() - before
+    assert grown <= 4
+    for k in (4, 7, 6, 31, 17):                      # the same buckets
+        write(k)
+    assert scatter._cache_size() - before == grown
+
+    last = view.blocks[-1]
+    last.ensure_rows(last.n_procs + 2)               # a host's range grows
+    last.set_entries([last.n_procs - 1], 1, 0.5)
+    view.refresh(V)
+    assert view.full_uploads == 2 and view.last_upload_rows == P + 2
+    assert view.time_blocks()[0].shape == (P + 2, V)
+    _assert_buffers_match(view, V)
 
 
 # ---------------------------------------------------------------------------

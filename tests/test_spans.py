@@ -2,8 +2,9 @@
 
 A real ``jax.profiler`` trace on the CPU around one smoke-size diagnosis
 cycle (detection kernels in Pallas interpret mode, the code path a chip
-runs) and one sampled plus one compiled train step: every name in
-``NAMES`` is emitted, nested as the layers nest, with its stats.  The
+runs), one sampled plus one compiled train step, and one fused op handed
+several row blocks: every name in ``NAMES`` is emitted, nested as the
+layers nest, with its stats.  The
 names stay clear of the chip benchmark's own spans, and the analysis
 layer keeps importing and running without jax."""
 import collections
@@ -32,7 +33,9 @@ PARENTS = {
     "detect.non_scalable": None,
     "detect.abnormal": None,
     "feed.refresh": {"detect.non_scalable", "detect.abnormal"},
-    "detect.concat": {"detect.non_scalable", "detect.abnormal"},
+    # only a caller handing a fused op several row blocks concatenates;
+    # detection's device views hand it one resident (P, V) buffer
+    "detect.concat": None,
     "detect.readback": {"detect.non_scalable", "detect.abnormal"},
     "backtrack": None,
     "root_causes": {None, "report.render"},
@@ -44,7 +47,8 @@ STATS = {
     "profiler.sampled_step": {"eqns"},
     "profiler.fence": {"vid"},
     "store.apply_rows": {"rows"},
-    "feed.refresh": {"blocks", "dirty_blocks", "rows", "bytes", "full"},
+    "feed.refresh": {"blocks", "dirty_blocks", "rows", "bytes", "full",
+                     "scatters"},
     "detect.concat": {"operands"},
     "store.stack": {"shards"},
 }
@@ -79,6 +83,7 @@ def _diagnose(series):
 
 @pytest.fixture(scope="module")
 def recorded(tmp_path_factory):
+    import jax.numpy as jnp
     from jax.profiler import ProfileData
     from repro.configs import get_smoke
     from repro.configs.base import RunConfig, ShapeConfig
@@ -96,9 +101,11 @@ def recorded(tmp_path_factory):
         _, series = _cycle_inputs()
         _diagnose(series)                          # compiles, full upload
         out = str(tmp_path_factory.mktemp("trace"))
+        blocks = [jnp.ones((2, 4), jnp.float32)] * 2
         with jax.profiler.trace(out):
             tr.train(num_steps=2, state=state)     # sampled, then compiled
             report = _diagnose(series)
+            ops.fused_abnormal(blocks, None, 1.5, 0.01, 2, step_time=1.0)
     assert "Root causes" in report
     path = next(os.path.join(d, f) for d, _, fs in os.walk(out)
                 for f in fs if f.endswith(".xplane.pb"))

@@ -113,3 +113,18 @@ def test_detection_programs_have_stable_names(one_chip, program):
     assert text.splitlines()[0].startswith(f"module @{module} ")
     if kernel is not None:
         assert f'kernel_name = "{kernel}"' in text
+
+
+def test_row_scatter_compiles_at_the_resident_shape(one_chip):
+    """The device feed's one row scatter per matrix a refresh, at the
+    8,192-process fleet's resident (P, V) buffer with 32 dirty rows (8
+    hosts of 4), compiles and keeps the module name a trace reads."""
+    from repro.core.shard import _row_scatter
+    P, V, rows = 8192, 128, 32
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in [((P, V), jnp.float32), ((rows,), jnp.int32),
+                         ((rows, V), jnp.float32)]]
+    lowered = _row_scatter().lower(*args)
+    assert lowered.as_text().splitlines()[0].startswith(
+        "module @jit_scatter_rows ")
+    assert "scatter" in lowered.compile().as_text()
