@@ -43,8 +43,10 @@ class ArchConfig:
     ssm_chunk: int = 64              # SSD chunk length
     conv_width: int = 4
 
-    # --- hybrid (zamba2-style): shared attention block every k layers ---
-    attn_every: int = 0
+    # --- hybrid (Zamba2): shared attention+MLP blocks over concat(h, e0) ---
+    attn_every: int = 0              # layer i is hybrid iff i % k == k - 1
+    n_shared_blocks: int = 0         # shared blocks, called in turn
+    adapter_rank: int = 0            # per-hybrid-layer LoRA on the MLP gate/up
 
     # --- enc-dec (seamless-m4t backbone): encoder depth; n_layers = decoder ---
     enc_layers: int = 0
@@ -80,14 +82,20 @@ class ArchConfig:
     def cdtype(self):
         return jnp.dtype(self.compute_dtype)
 
+    @property
+    def n_hybrid_layers(self) -> int:
+        return self.n_layers // self.attn_every if self.attn_every else 0
+
     # ------------------------------------------------------------------
-    # Rough parameter count (for roofline MODEL_FLOPS = 6 N D).
+    # Parameter count (for roofline MODEL_FLOPS = 6 N D).
     # ------------------------------------------------------------------
     def param_count(self, active_only: bool = False) -> int:
         d = self.d_model
         h = self.resolved_head_dim() if self.n_heads else 0
         n_q, n_kv = self.n_heads, self.n_kv_heads
-        attn = d * (n_q * h) + 2 * d * (n_kv * h) + (n_q * h) * d
+
+        def attn_params(d_in):
+            return d_in * (n_q + 2 * n_kv) * h + (n_q * h) * d
 
         def mlp_params(ff):
             gates = 3 if self.mlp in ("swiglu", "geglu") else 2
@@ -99,25 +107,28 @@ class ArchConfig:
         else:
             mlp = mlp_params(self.d_ff)
 
-        if self.family == "ssm":
-            di, ns = self.d_inner, self.ssm_state
-            # in_proj (x,z,B,C,dt) + out_proj + conv + A,D
-            blk = d * (2 * di + 2 * ns + self.ssm_heads) + di * d \
-                + self.conv_width * (di + 2 * ns) + 2 * self.ssm_heads
-            per_layer = blk + d  # + norm
-        elif self.family == "hybrid":
-            di, ns = self.d_inner, self.ssm_state
-            blk = d * (2 * di + 2 * ns + self.ssm_heads) + di * d \
-                + self.conv_width * (di + 2 * ns) + 2 * self.ssm_heads
-            per_layer = blk + mlp + 2 * d
+        if self.family in ("ssm", "hybrid"):
+            di, ns, hh = self.d_inner, self.ssm_state, self.ssm_heads
+            # z, x, B, C, dt projections + out_proj + conv taps and biases
+            # + dt_bias, A_log, D + gated-norm scale
+            per_layer = d * (2 * di + 2 * ns + hh) + di * d \
+                + (self.conv_width + 1) * (di + 2 * ns) + 3 * hh + di
+            per_layer += d                                   # + norm
         else:
-            per_layer = attn + mlp + 2 * d
+            per_layer = attn_params(d) + mlp + 2 * d
 
         n_blocks = self.n_layers + self.enc_layers
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         total = n_blocks * per_layer + emb + d
-        if self.family == "hybrid" and self.attn_every:
-            total += attn + d                      # one shared attention block
+        if self.family == "hybrid":
+            # each shared block once: norms, attention over concat(h, e0)
+            # (width 2d) and the MLP; per hybrid layer its LoRA adapter on
+            # the MLP's gate and up projections and its d -> d linear
+            shared = 2 * d + attn_params(2 * d) + d + mlp
+            gates = 2 if self.mlp in ("swiglu", "geglu") else 1
+            per_hybrid = self.adapter_rank * (d + gates * self.d_ff) + d * d
+            total += self.n_shared_blocks * shared \
+                + self.n_hybrid_layers * per_hybrid
         return int(total)
 
 

@@ -42,7 +42,7 @@ import numpy as np
 
 from repro.core.graph import COMM, COMP, LOOP, PPG
 from repro.core.shard import ShardedStore
-from repro.core.spans import spanned
+from repro.core.spans import span, spanned
 
 MERGE_STRATEGIES = ("mean", "median", "max", "p0", "cluster", "var")
 
@@ -383,7 +383,6 @@ def detect_non_scalable(series: Mapping[int, PPG], *,
     return out[:top_k]
 
 
-@spanned("detect.abnormal")
 def detect_abnormal(ppg: PPG, *, abnorm_thd: float = 1.3,
                     min_share: float = 0.01,
                     top_k: int = 20,
@@ -400,7 +399,18 @@ def detect_abnormal(ppg: PPG, *, abnorm_thd: float = 1.3,
     monitor's degraded-fleet contract).  False rows are excluded from the
     step time, the median and the flagging by exact row-subsetting (see
     :func:`_norm_mask`); reported ``proc`` indices stay global.  On the
-    device path the live rows are gathered on the device."""
+    device path the live rows are gathered on the device.
+
+    Runs in the span ``detect.abnormal``; on the device path its stat
+    ``col_tiles`` counts the kernel's 128-column tiles."""
+    with span("detect.abnormal") as sp:
+        return _detect_abnormal(sp, ppg, abnorm_thd, min_share, top_k,
+                                backend, proc_mask)
+
+
+def _detect_abnormal(sp, ppg: PPG, abnorm_thd: float, min_share: float,
+                     top_k: int, backend: Optional[str],
+                     proc_mask: Optional[np.ndarray]) -> List[Abnormal]:
     psg = ppg.psg
     if not len(psg.vertices) or not ppg.n_procs:
         return []
@@ -421,6 +431,7 @@ def detect_abnormal(ppg: PPG, *, abnorm_thd: float = 1.3,
         # buffer (dirty rows re-upload per call), and the step time,
         # median, flagging and ranking all run device-side — the stacked
         # (P, V) host matrix is never materialized
+        sp.set_metadata(col_tiles=jx.col_tiles(len(psg.vertices)))
         vids, procs, typical, _ = jx.abnormal_topk_view(
             ppg.device_view(), len(psg.vertices), top, abnorm_thd,
             min_share, top_k, live_rows=live_idx)

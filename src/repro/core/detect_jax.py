@@ -334,6 +334,11 @@ def abnormal_topk(t: np.ndarray, abnorm_thd: float, min_share: float,
     return order // n_procs, order % n_procs, typical, n_flagged
 
 
+def col_tiles(n_vertices: int) -> int:
+    """The fused abnormal kernel's column tiles over ``n_vertices``."""
+    return _fused._lanes(n_vertices) // _fused._COL_TILE
+
+
 def abnormal_topk_view(view, n_vertices: int, top: Sequence[int],
                        abnorm_thd: float, min_share: float, k: int,
                        live_rows: Optional[np.ndarray] = None,

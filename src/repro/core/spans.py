@@ -9,6 +9,11 @@ Without jax in the process there is no profiler to record, so the span is
 a null context and the analysis layer stays importable without jax.
 
 ``NAMES`` lists every span name the program emits, without the prefix.
+
+``scope(name)`` is the device-side counterpart: a ``jax.named_scope``
+that puts ``name`` into the name stack of every operation traced inside
+it, and so into the HLO metadata (``op_name``) and the device trace's op
+stats.  ``SCOPES`` lists every such name.
 """
 from __future__ import annotations
 
@@ -24,6 +29,8 @@ NAMES = (
     "detect.readback", "feed.refresh",
     "backtrack", "root_causes", "report.render",
 )
+
+SCOPES = ("hybrid.shared_block",)
 
 
 class _Inert(contextlib.nullcontext):
@@ -53,3 +60,12 @@ def spanned(name: str):
                 return fn(*args, **kwargs)
         return inner
     return wrap
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name in ``SCOPES``; called only by
+    code that traces jax programs."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not in SCOPES {SCOPES}")
+    import jax
+    return jax.named_scope(name)

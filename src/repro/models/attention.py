@@ -24,12 +24,15 @@ def wo_matrix(p: Dict[str, jax.Array]) -> jax.Array:
 NEG_INF = -1e30
 
 
-def attention_specs(cfg: ArchConfig) -> Dict[str, P]:
+def attention_specs(cfg: ArchConfig, d_in: Optional[int] = None
+                    ) -> Dict[str, P]:
+    """q/k/v read ``d_in`` features (default d_model); wo writes d_model."""
     d, h = cfg.d_model, cfg.resolved_head_dim()
+    d_in = d_in or d
     return {
-        "wq": P((d, cfg.n_heads * h), ("embed", "q_features")),
-        "wk": P((d, cfg.n_kv_heads * h), ("embed", "kv_features")),
-        "wv": P((d, cfg.n_kv_heads * h), ("embed", "kv_features")),
+        "wq": P((d_in, cfg.n_heads * h), ("embed", "q_features")),
+        "wk": P((d_in, cfg.n_kv_heads * h), ("embed", "kv_features")),
+        "wv": P((d_in, cfg.n_kv_heads * h), ("embed", "kv_features")),
         "wo": P((cfg.n_heads * h, d), ("q_features", "embed")),
     }
 
@@ -153,11 +156,14 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
 
 
 def attention_train(cfg: ArchConfig, p: Dict[str, jax.Array], x: jax.Array,
-                    *, causal: bool = True) -> jax.Array:
+                    *, causal: bool = True,
+                    softmax_scale: Optional[float] = None) -> jax.Array:
+    """x may be wider than d_model (see :func:`attention_specs`); the
+    softmax scale defaults to head_dim ** -0.5."""
     B, S, _ = x.shape
     positions = jnp.arange(S)[None, :]
     q, k, v = qkv(cfg, p, x, positions)
-    scale = cfg.resolved_head_dim() ** -0.5
+    scale = softmax_scale or cfg.resolved_head_dim() ** -0.5
     if cfg.use_kernels:
         from repro.kernels.flash_attention.ops import flash_attention
         out = flash_attention(q, k, v, causal=causal, softmax_scale=scale)
@@ -213,9 +219,10 @@ def kv_cache_specs(cfg: ArchConfig, batch: int, max_len: int, n_layers: int,
 
 def attention_decode(cfg: ArchConfig, p: Dict[str, jax.Array], x: jax.Array,
                      k_cache: jax.Array, v_cache: jax.Array,
-                     lengths: jax.Array
+                     lengths: jax.Array, *,
+                     softmax_scale: Optional[float] = None
                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token decode. x: (B, 1, D); caches (B, S_max, n_kv, h).
+    """One-token decode. x: (B, 1, D_in); caches (B, S_max, n_kv, h).
 
     Returns (out (B,1,D), new_k_cache, new_v_cache).
     """
@@ -232,6 +239,7 @@ def attention_decode(cfg: ArchConfig, p: Dict[str, jax.Array], x: jax.Array,
     valid = (jnp.arange(S_max)[None, :] <= lengths[:, None])        # (B, S_max)
     mask = valid[:, None, None, None, :]                            # b k g s t
     out = gqa_attend(q, k_cache, v_cache, mask,
-                     softmax_scale=cfg.resolved_head_dim() ** -0.5)
+                     softmax_scale=softmax_scale
+                     or cfg.resolved_head_dim() ** -0.5)
     out = out.reshape(B, one, -1) @ wo_matrix(p)
     return out, k_cache, v_cache

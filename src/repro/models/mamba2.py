@@ -97,11 +97,16 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     CB = jnp.einsum("bcqn,bckn->bcqk", Cc, Bc)               # (B,nc,Q,Q)
     L = s[:, :, :, None, :] - s[:, :, None, :, :]            # s_i - s_j (B,nc,Q,Q,H)
     causal = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.where(causal[None, None, :, :, None], jnp.exp(L), 0.0)
+    # mask the exponent, not its value: above the diagonal s_i - s_j > 0
+    # grows with the chunk's decay, and exp of it overflows float32 (a 0
+    # times inf in the gradient) at Mamba-2's published A and dt draws
+    L = jnp.exp(jnp.where(causal[None, None, :, :, None], L, -jnp.inf))
     M = CB[..., None] * L * dtc[:, :, None, :, :]            # (B,nc,Q,Q,H)
     y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", M, xc)
 
     # chunk states: St_c = sum_j exp(s_Q - s_j) dt_j B_j (x) x_j  -> (B,nc,H,N,P)
+    # s is non-increasing along the chunk (dt >= 0, A < 0): this and the
+    # other exponents below are <= 0 and cannot overflow
     decay_to_end = jnp.exp(s[:, :, -1:, :] - s)              # (B,nc,Q,H)
     st = jnp.einsum("bcqh,bcqn,bcqhp->bchnp",
                     decay_to_end * dtc, Bc, xc)

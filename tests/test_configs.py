@@ -91,3 +91,17 @@ def test_param_counts_near_published():
     for arch, n in expect.items():
         got = get(arch).param_count()
         assert 0.5 * n < got < 2.2 * n, (arch, got, n)
+
+
+@pytest.mark.parametrize("cfg", [get_smoke("zamba2-2.7b"), get("zamba2-2.7b")],
+                         ids=["smoke", "published"])
+def test_hybrid_param_count_matches_the_specs(cfg):
+    """Each shared block once, an adapter and a linear per hybrid layer."""
+    from repro.models.api import build_model
+    assert cfg.param_count() == build_model(cfg).param_count()
+
+
+def test_zamba2_published_total():
+    # 54 Mamba2 layers, two shared blocks, nine adapters + linears, the
+    # tied embedding: 2.66 B
+    assert get("zamba2-2.7b").param_count() == 2_662_214_560

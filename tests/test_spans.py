@@ -10,6 +10,7 @@ layer keeps importing and running without jax."""
 import collections
 import importlib.util
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -51,6 +52,7 @@ STATS = {
                      "scatters"},
     "detect.concat": {"operands"},
     "store.stack": {"shards"},
+    "detect.abnormal": {"col_tiles"},
 }
 
 
@@ -145,6 +147,57 @@ def test_spans_nest_as_the_layers_do(recorded):
                 assert holders <= allowed, (name, holders)
             else:
                 assert holders & allowed, (name, holders)
+
+
+def test_abnormal_span_counts_two_column_tiles_above_128_vertices(tmp_path):
+    from jax.profiler import ProfileData
+    from repro.core import detect_abnormal
+    from repro.core.inject import simulate
+    from repro.kernels.detect_fused import ops
+    from tests.test_device_detect import _step_psg
+    g = _step_psg(16, n_comp=140)
+    assert len(g.vertices) > 128
+    ppg = simulate(g, 16, lambda p, v: 0.01 + 0.001 * v
+                   + (1.0 if (p, v) == (3, 2) else 0.0), shards=2).ppg
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "kernel_mode", lambda interpret=None: "interpret")
+        mp.setenv("SCALANA_DETECT_F32", "1")
+        detect_abnormal(ppg, backend="jax")            # compiles outside
+        with jax.profiler.trace(str(tmp_path)):
+            found = detect_abnormal(ppg, backend="jax")
+    assert (3, 2) in {(a.proc, a.vid) for a in found}
+    path = next(os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+                for f in fs if f.endswith(".xplane.pb"))
+    tiles = [dict(ev.stats).get("col_tiles")
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name == "scalana.detect.abnormal"]
+    assert tiles == [2]
+
+
+def test_shared_block_scope_reaches_the_train_steps_hlo():
+    """Every op of a shared-block call carries ``hybrid.shared_block``
+    in its name stack, which the HLO keeps as op metadata and the device
+    trace as each op's name stack."""
+    import jax.numpy as jnp
+    from repro.configs import get_smoke
+    from repro.configs.base import RunConfig, ShapeConfig
+    from repro.training import Trainer
+    assert spans.SCOPES == ("hybrid.shared_block",)
+    cfg = get_smoke("zamba2-2.7b")
+    tr = Trainer(RunConfig(arch=cfg.name, scalana=False), arch_cfg=cfg,
+                 shape=ShapeConfig("scope", 16, 2, "train"))
+    state = jax.eval_shape(tr.init_state)
+    tokens = jax.ShapeDtypeStruct((2, 17), jnp.int32)
+    hlo = jax.jit(tr.train_step_fn).lower(
+        state, {"tokens": tokens}).compile().as_text()
+    named = re.findall(r'op_name="([^"]*hybrid\.shared_block[^"]*)"', hlo)
+    assert any(n.endswith("dot_general") for n in named)
+    assert any(n.startswith("jit(train_step)/jvp(") for n in named)
+    assert any("transpose(" in n for n in named)       # the backward too
+    assert any("rematted_computation" in n for n in named)   # recompute
+    with pytest.raises(ValueError):
+        spans.scope("hybrid.other_block")
 
 
 def test_names_differ_from_the_benchmark_spans():
