@@ -26,6 +26,7 @@ Complexity guarantees (the indexed graph core):
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 import weakref
 from dataclasses import dataclass, field
@@ -44,6 +45,12 @@ COMM = "Comm"
 ROOT = "Root"
 
 KINDS = (LOOP, BRANCH, CALL, COMP, COMM, ROOT)
+
+# Write generations: a PerfStore takes a fresh one from this one count at
+# construction and at every write, so a generation names one state of one
+# store — a reader that recorded it knows the store is unchanged while it
+# still reads the same number (``ShardedStore``'s resident stacked reads).
+_GENERATIONS = itertools.count()
 
 
 def pairs_array(pairs) -> np.ndarray:
@@ -469,7 +476,7 @@ class PerfStore:
     """
 
     __slots__ = ("n_procs", "_cols", "time", "time_var", "samples",
-                 "_mask", "_counters", "_count", "_dirty")
+                 "_mask", "_counters", "_count", "_dirty", "_gen")
 
     def __init__(self, n_procs: int, n_vertices: int = 0):
         self.n_procs = int(n_procs)
@@ -484,6 +491,9 @@ class PerfStore:
         # rows written since the last clear_dirty() — the device-resident
         # buffer layer (shard.DeviceShardView) re-uploads only these
         self._dirty = np.zeros(self.n_procs, bool)
+        # write generation (see ``_GENERATIONS``): moves at every write,
+        # independent of the dirty flags, which a device view clears
+        self._gen = next(_GENERATIONS)
 
     # -- storage management --------------------------------------------
     def _grow(self, arr: np.ndarray, cols: int) -> np.ndarray:
@@ -500,6 +510,7 @@ class PerfStore:
         self.samples = self._grow(self.samples, cols)
         self._mask = self._grow(self._mask, cols)
         self._cols = cols
+        self._gen = next(_GENERATIONS)
 
     def ensure_rows(self, n_procs: int) -> None:
         """Grow the proc dimension exactly to ``n_procs`` (streamed shard
@@ -524,6 +535,7 @@ class PerfStore:
         for cc in self._counters.values():
             cc.ensure_rows(n_procs)
         self.n_procs = int(n_procs)
+        self._gen = next(_GENERATIONS)
 
     # -- dirty-row tracking (device-resident buffer feed) --------------
     def dirty_rows(self) -> np.ndarray:
@@ -610,6 +622,7 @@ class PerfStore:
         self._count += int(newly)
         self._mask[idx, vid] = True
         self._dirty[idx] = True
+        self._gen = next(_GENERATIONS)
         self.time[idx, vid] = time
         self.time_var[idx, vid] = time_var
         self.samples[idx, vid] = samples
@@ -647,6 +660,7 @@ class PerfStore:
         self._count += int(np.count_nonzero(touched & ~col_mask))
         col_mask |= touched
         self._dirty |= touched
+        self._gen = next(_GENERATIONS)
         t = np.broadcast_to(np.asarray(time, float), procs.shape)
         if not accumulate:
             self.time[procs, vid] = t
@@ -692,6 +706,7 @@ class PerfStore:
             self._count += 1
             self._mask[p, vid] = True
         self._dirty[p] = True
+        self._gen = next(_GENERATIONS)
         if accumulate:
             self.time[p, vid] += time
         else:
@@ -750,6 +765,7 @@ class PerfStore:
             return
         with span("store.apply_rows", rows=rows.size):
             self.ensure_columns(block.n_cols)
+            self._gen = next(_GENERATIONS)
             c = block.n_cols
             old = int(np.count_nonzero(self._mask[rows]))
             self._mask[rows] = False
@@ -815,6 +831,7 @@ class PerfStore:
         rows, cols = time.shape
         self.ensure_rows(rows)
         self.ensure_columns(cols)
+        self._gen = next(_GENERATIONS)
         self.time[:, :] = 0.0
         self.time_var[:, :] = 0.0
         self.samples[:, :] = 0
@@ -873,6 +890,7 @@ class PerfStore:
         rows = slice(off, off + shard.n_procs)
         cols = shard._cols
         msk = shard._mask
+        self._gen = next(_GENERATIONS)
         np.copyto(self.time[rows, :cols], shard.time, where=msk)
         np.copyto(self.time_var[rows, :cols], shard.time_var, where=msk)
         np.copyto(self.samples[rows, :cols], shard.samples, where=msk)
@@ -965,6 +983,7 @@ class PerfStore:
             self._count += 1
         self._mask[p, vid] = True
         self._dirty[p] = True
+        self._gen = next(_GENERATIONS)
         self.time[p, vid] = vec.time
         self.time_var[p, vid] = vec.time_var
         self.samples[p, vid] = vec.samples

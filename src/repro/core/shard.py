@@ -11,8 +11,9 @@ by ``proc_start``), and the blocks merge late, either
   or
 * not at all — :class:`ShardedStore` keeps the per-host blocks and serves
   the PerfStore API on top: writes route to the owning shard by proc
-  range, matrix reads are STACKED VIEWS (per-shard blocks concatenated on
-  demand), so the detectors consume multi-host data without ever
+  range, matrix reads are STACKED VIEWS (per-shard blocks stacked into a
+  resident matrix that re-copies only the blocks written since the last
+  read), so the detectors consume multi-host data without ever
   densifying it into a merged store.
 
 ``repro.core.inject.simulate(..., shards=...)`` executes the replay engine
@@ -29,6 +30,7 @@ never imports jax (the view imports it lazily inside ``refresh``).
 """
 from __future__ import annotations
 
+import sys
 from typing import (Any, Dict, Iterator, List, Mapping, Optional, Sequence,
                     Tuple)
 
@@ -140,6 +142,19 @@ class PerfShard(PerfStore):
                 f"{len(self)} entries)")
 
 
+class _Resident:
+    """One stacked read kept between calls: its arrays, the column count
+    they were read at, and each shard's write generation and row count
+    when they were last brought up to date."""
+
+    __slots__ = ("arrays", "n", "gens", "rows", "vids")
+
+    def __init__(self, arrays: list, n: int, gens: np.ndarray,
+                 rows: np.ndarray, vids: Optional[list] = None):
+        self.arrays, self.n, self.gens, self.rows = arrays, n, gens, rows
+        self.vids = vids                # counter_columns: per-shard vids
+
+
 class ShardedStore:
     """Per-host :class:`PerfShard` blocks behind the PerfStore API.
 
@@ -147,15 +162,28 @@ class ShardedStore:
     proc index to the shard owning its range — a row's writes keep their
     order, so accumulate-mode scatters are bit-identical to the unsharded
     store.  Matrix reads (``time_matrix`` / ``var_matrix`` /
-    ``counter_columns``) are stacked shard views: per-host blocks
-    concatenated on demand, never scattered into a merged store.  Use
-    :meth:`merge` when a genuinely single store is needed.
+    ``counter_matrix`` / ``counter_columns``) are stacked shard views:
+    per-host blocks stacked into one resident result per read, never
+    scattered into a merged store.  Use :meth:`merge` when a genuinely
+    single store is needed.
+
+    A stacked read keeps its result and each shard's write generation
+    (``PerfStore._gen``); the next read copies again only the rows of
+    the shards whose generation moved.  A changed column count (or a
+    ``counter_columns`` union that gains or loses a column), a shard
+    whose row count changed, or a different list of shards stacks every
+    block again.  The arrays handed out are read-only views of the
+    resident ones, equal to a fresh ``np.vstack``; a read that would
+    update them in place while a caller still holds one copies first, so
+    an array read earlier never changes under its holder.  Reads update
+    the resident results, so concurrent readers need the lock their
+    writers already need.
 
     Ranges must tile ``[0, n_procs)`` contiguously (the replay engine
     writes every process).
     """
 
-    __slots__ = ("shards", "n_procs", "_starts")
+    __slots__ = ("shards", "n_procs", "_starts", "_reads")
 
     def __init__(self, ranges: Sequence[Tuple[int, int]], n_vertices: int = 0):
         ranges = [(int(lo), int(hi)) for lo, hi in ranges]
@@ -171,6 +199,7 @@ class ShardedStore:
                                         for lo, hi in ranges]
         self.n_procs = ranges[-1][1]
         self._starts = np.asarray([lo for lo, _ in ranges], np.intp)
+        self._reads: Dict[Tuple[str, str], _Resident] = {}
 
     @classmethod
     def of(cls, shards) -> "ShardedStore":
@@ -262,40 +291,113 @@ class ShardedStore:
     def _cols(self) -> int:
         return max(sh._cols for sh in self.shards)
 
-    # each stacked read is the span ``store.stack``, its stat ``shards``
+    # each stacked read is the span ``store.stack``, its stats ``shards``
+    # and ``restacked`` (the shards copied: all of them, or those written
+    # since the last read)
     def time_matrix(self, n_vertices: Optional[int] = None) -> np.ndarray:
         n = self._cols if n_vertices is None else n_vertices
-        with span("store.stack", shards=len(self.shards)):
-            return np.vstack([sh.time_matrix(n) for sh in self.shards])
+        return self._stacked(("time", ""), n, lambda sh: sh.time_matrix(n))
 
     def var_matrix(self, n_vertices: Optional[int] = None) -> np.ndarray:
         n = self._cols if n_vertices is None else n_vertices
-        with span("store.stack", shards=len(self.shards)):
-            return np.vstack([sh.var_matrix(n) for sh in self.shards])
+        return self._stacked(("var", ""), n, lambda sh: sh.var_matrix(n))
 
     def counter_matrix(self, name: str,
                        n_vertices: Optional[int] = None) -> np.ndarray:
         n = self._cols if n_vertices is None else n_vertices
-        with span("store.stack", shards=len(self.shards)):
-            return np.vstack([sh.counter_matrix(name, n)
-                              for sh in self.shards])
+        return self._stacked(("counter", name), n,
+                             lambda sh: sh.counter_matrix(name, n))
+
+    def _changed(self, key: Tuple[str, str], n: int
+                 ) -> Tuple[Optional[_Resident], np.ndarray, np.ndarray]:
+        """(resident read or None, shards' generations, indices of the
+        shards written since it).  None: stack every shard again."""
+        gens = np.fromiter((sh._gen for sh in self.shards), np.int64,
+                           len(self.shards))
+        have = self._reads.get(key)
+        if have is not None and have.n == n and have.gens.size == gens.size:
+            changed = np.flatnonzero(gens != have.gens)
+            if all(self.shards[i].n_procs == have.rows[i]
+                   for i in changed.tolist()):
+                return have, gens, changed
+        return None, gens, np.arange(gens.size)
+
+    def _own(self, have: _Resident, j: int) -> np.ndarray:
+        """``have.arrays[j]``, for writing in place: copied first where a
+        read-only view handed out earlier is still alive (a view holds
+        the array as its base; unheld, the list slot and this call's
+        argument are its only references)."""
+        if sys.getrefcount(have.arrays[j]) > 2:
+            have.arrays[j] = have.arrays[j].copy()
+        return have.arrays[j]
+
+    def _stacked(self, key: Tuple[str, str], n: int, block) -> np.ndarray:
+        """The resident (n_procs, n) stacked read ``key``: ``block(shard)``
+        of each shard at its rows, re-copying only changed shards."""
+        with span("store.stack", shards=len(self.shards)) as sp:
+            have, gens, changed = self._changed(key, n)
+            if have is None:
+                rows = np.asarray([sh.n_procs for sh in self.shards])
+                have = self._reads[key] = _Resident(
+                    [np.vstack([block(sh) for sh in self.shards])], n, gens,
+                    rows)
+            elif changed.size:
+                buf = self._own(have, 0)
+                starts = np.cumsum(have.rows) - have.rows
+                for i in changed.tolist():
+                    lo = starts[i]
+                    buf[lo:lo + have.rows[i]] = block(self.shards[i])
+                have.gens = gens
+            sp.set_metadata(restacked=changed.size)
+            return _read_only(have.arrays[0])
 
     def counter_columns(self, name: str
                         ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Stacked compressed view: the union of the shards' written
-        columns, each shard's block placed at its row range."""
-        with span("store.stack", shards=len(self.shards)):
-            per = [sh.counter_columns(name) for sh in self.shards]
-            vids = np.unique(np.concatenate([v for v, _, _ in per]))
-            values = np.zeros((self.n_procs, vids.size))
-            mask = np.zeros((self.n_procs, vids.size), bool)
-            for sh, (v, val, m) in zip(self.shards, per):
-                if not v.size:
-                    continue
-                slots = np.searchsorted(vids, v)
-                values[sh.proc_start:sh.proc_stop, slots] = val
-                mask[sh.proc_start:sh.proc_stop, slots] = m
-            return vids, values, mask
+        columns, each shard's block placed at its row range.  Resident as
+        the matrices are; the union is rebuilt when a written shard's
+        columns change other than by appending ones the union holds."""
+        key = ("columns", name)
+        with span("store.stack", shards=len(self.shards)) as sp:
+            have, gens, changed = self._changed(key, 0)
+            per = {i: self.shards[i].counter_columns(name)
+                   for i in changed.tolist()}
+            if have is not None:
+                union = have.arrays[0]
+                for i, (v, _, _) in per.items():
+                    old = have.vids[i]
+                    if (v.size < old.size
+                            or not np.array_equal(v[:old.size], old)
+                            or not np.isin(v[old.size:], union).all()):
+                        have = None
+                        break
+            if have is None:
+                if len(per) < len(self.shards):
+                    per = {i: sh.counter_columns(name)
+                           for i, sh in enumerate(self.shards)}
+                vids = np.unique(np.concatenate([v for v, _, _ in
+                                                 per.values()]))
+                rows = np.asarray([sh.n_procs for sh in self.shards])
+                have = self._reads[key] = _Resident(
+                    [vids, np.zeros((self.n_procs, vids.size)),
+                     np.zeros((self.n_procs, vids.size), bool)], 0, gens,
+                    rows, [None] * len(self.shards))
+            if per:
+                vids = have.arrays[0]
+                values, mask = self._own(have, 1), self._own(have, 2)
+                for i, (v, val, m) in per.items():
+                    # the shard's columns only grew inside the union, so
+                    # its row block is zero outside them already
+                    sh = self.shards[i]
+                    blk = slice(sh.proc_start, sh.proc_stop)
+                    if v.size:
+                        slots = np.searchsorted(vids, v)
+                        values[blk, slots] = val
+                        mask[blk, slots] = m
+                    have.vids[i] = v
+                have.gens = gens
+            sp.set_metadata(restacked=len(per))
+            return tuple(_read_only(a) for a in have.arrays)
 
     def time_column(self, vid: int) -> np.ndarray:
         return np.concatenate([sh.time_column(vid) for sh in self.shards])
@@ -664,6 +766,14 @@ class DeviceShardView:
             f"{self._cols} cols, {np.dtype(self._dtype).name}"
         return (f"DeviceShardView({len(self.blocks)} blocks, "
                 f"{self.n_procs} procs, {state})")
+
+
+def _read_only(arr: np.ndarray) -> np.ndarray:
+    """A read-only view of ``arr``: a caller that writes into a resident
+    stacked read fails loudly instead of corrupting it."""
+    view = arr.view()
+    view.flags.writeable = False
+    return view
 
 
 def _take(val, sel: np.ndarray):

@@ -289,6 +289,144 @@ def test_sharded_store_routes_like_plain_store(plan, accumulate):
         assert np.array_equal(mp[:, order_p], ms[:, order_s])
 
 
+# ---------------------------------------------------------------------------
+# ShardedStore: resident stacked reads == a fresh stack after every seam
+# ---------------------------------------------------------------------------
+
+def _seeded_store():
+    """Four uneven shards over 12 procs and 12 columns; ``bytes`` lives
+    at column 11 only in shard 0 and at column 9 in shard 3, so replacing
+    shard 0 with a block that holds it at 9 shrinks the stacked union of
+    its columns."""
+    store = ShardedStore([(0, 3), (3, 4), (4, 9), (9, 12)], 12)
+    procs = np.arange(12)
+    for vid in range(1, 8):
+        store.set_entries(procs, vid, 0.01 * vid + 0.001 * procs,
+                          time_var=1e-4 * vid, samples=2,
+                          counters={"flops": 10.0 * vid} if vid < 4 else {})
+    store.set_entries(procs, 10, 0.5 + 0.01 * procs,
+                      counters={"wait_s": 0.1 + 0.01 * procs})
+    store.set_entries([0, 2], 11, 0.75, counters={"bytes": [64.0, 32.0]})
+    store.set_entries([10], 9, 0.5, counters={"bytes": 16.0})
+    return store
+
+
+def _fresh_reads(store):
+    """Today's reads the stack-everything way: ``np.vstack`` of every
+    shard's block, and the union of the shards' counter columns."""
+    n = store._cols
+    out = {"time": np.vstack([sh.time_matrix(n) for sh in store.shards]),
+           "var": np.vstack([sh.var_matrix(n) for sh in store.shards])}
+    for name in store.counter_names() + ["absent"]:
+        out[name] = np.vstack([sh.counter_matrix(name, n)
+                               for sh in store.shards])
+        per = [sh.counter_columns(name) for sh in store.shards]
+        vids = np.unique(np.concatenate([v for v, _, _ in per]))
+        values = np.zeros((store.n_procs, vids.size))
+        mask = np.zeros((store.n_procs, vids.size), bool)
+        for sh, (v, val, m) in zip(store.shards, per):
+            slots = np.searchsorted(vids, v)
+            values[sh.proc_start:sh.proc_stop, slots] = val
+            mask[sh.proc_start:sh.proc_stop, slots] = m
+        out[name + ".columns"] = (vids, values, mask)
+    return out
+
+
+def _store_reads(store):
+    out = {"time": store.time_matrix(), "var": store.var_matrix()}
+    for name in store.counter_names() + ["absent"]:
+        out[name] = store.counter_matrix(name)
+        out[name + ".columns"] = store.counter_columns(name)
+    return out
+
+
+def _reads_equal(got, want):
+    assert set(got) == set(want)
+    for key, w in want.items():
+        g = got[key]
+        for a, b in zip(g if isinstance(g, tuple) else (g,),
+                        w if isinstance(w, tuple) else (w,)):
+            assert a.dtype == b.dtype and np.array_equal(a, b), key
+
+
+def _check_reads(store):
+    got = _store_reads(store)
+    _reads_equal(got, _fresh_reads(store))
+    for key, arrays in got.items():
+        for a in arrays if isinstance(arrays, tuple) else (arrays,):
+            assert not a.flags.writeable, key
+            with pytest.raises(ValueError):
+                a[...] = 0
+
+
+def _replace_shard(store, step):
+    """Shard 0 restored from another block's tree: new values, and
+    ``bytes`` at column 9 in place of 11."""
+    other = PerfShard(0, 3, 12)
+    other.set_column(9, 3.0 + step, counters={"bytes": 7.0})
+    other.set_column(10, 0.25, counters={"wait_s": 0.05})
+    store.shards[0].load_tree(*other.to_tree())
+
+
+def _apply_host_rows(store, step):
+    """A host's rows applied straight to its shard (the diagnosis
+    write in `drivers/diagnose.py`), with a counter column the shard
+    never held."""
+    sh = store.shards[2]
+    blk = sh.extract_rows([1, 3])
+    blk.time[:, 4] += 1.0 + step
+    blk.counters["wait_s"] = (np.asarray([10, 5]), np.full((2, 2), 0.3),
+                              np.ones((2, 2), bool))
+    sh.apply_rows(blk)
+
+
+STACK_SEAMS = {
+    "set_column": lambda s, k: s.set_column(
+        2, 0.1 * k + np.arange(12.0), counters={"flops": 5.0 + k}),
+    "set_column_procs": lambda s, k: s.set_column(
+        3, [1.0 + k, 2.0], procs=np.asarray([1, 9]),
+        counters={"wait_s": [0.2, 0.3]}),
+    "set_entries": lambda s, k: s.set_entries(
+        [0, 5, 5, 11], 4, [1.0, 2.0 + k, 3.0, 4.0],
+        counters={"flops": 1.0 + k}),
+    "set_entries_accumulate": lambda s, k: s.set_entries(
+        [3, 5, 5], 10, 0.5 + k, counters={"wait_s": 0.25},
+        accumulate=True),
+    "set_entry": lambda s, k: s.set_entry(
+        7, 1, 2.5 + k, time_var=0.5, counters={"wait_s": 0.125}),
+    "setitem": lambda s, k: s.__setitem__(
+        (4, 6), PerfVector(time=1.5 + k, counters={"bytes": 8.0})),
+    "apply_rows": _apply_host_rows,
+    "ensure_columns": lambda s, k: s.ensure_columns(s._cols + 1),
+    "new_counter_column": lambda s, k: s.set_entries(
+        [6], 8 + k, 0.5, counters={"wait_s": 0.4}),
+    "load_tree": _replace_shard,
+}
+
+
+@pytest.mark.parametrize("seam", sorted(STACK_SEAMS))
+def test_stacked_reads_equal_fresh_stack_after_each_write_seam(seam):
+    """Every write seam moves a shard's generation, so the resident
+    stacked reads re-copy it: they equal a fresh stack, are read-only,
+    and never change under a caller holding an earlier read — also
+    after a device view's refresh cleared the dirty flags the write
+    set."""
+    from repro.core.shard import DeviceShardView
+    write = STACK_SEAMS[seam]
+    store = _seeded_store()
+    held, before = _store_reads(store), _fresh_reads(store)
+    write(store, 0)
+    _check_reads(store)
+    _reads_equal(held, before)
+    del held
+    view = DeviceShardView(store)
+    view.refresh()
+    write(store, 1)
+    view.refresh()
+    assert not any(sh.dirty_rows().size for sh in store.shards)
+    _check_reads(store)
+
+
 def test_sharded_store_requires_contiguous_ranges():
     with pytest.raises(ValueError):
         ShardedStore([(0, 2), (3, 5)])

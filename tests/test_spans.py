@@ -51,7 +51,7 @@ STATS = {
     "feed.refresh": {"blocks", "dirty_blocks", "rows", "bytes", "full",
                      "scatters"},
     "detect.concat": {"operands"},
-    "store.stack": {"shards"},
+    "store.stack": {"shards", "restacked"},
     "detect.abnormal": {"col_tiles"},
 }
 
@@ -173,6 +173,36 @@ def test_abnormal_span_counts_two_column_tiles_above_128_vertices(tmp_path):
              for line in plane.lines for ev in line.events
              if ev.name == "scalana.detect.abnormal"]
     assert tiles == [2]
+
+
+def test_store_stack_counts_the_shards_written_since_the_last_read(tmp_path):
+    """Over 64 shards, a stacked read after writes to k of them copies
+    those k (the span's stat ``restacked``), and the read after it none;
+    the first read stacks all 64."""
+    from jax.profiler import ProfileData
+    from repro.core.shard import ShardedStore, shard_ranges
+    store = ShardedStore(shard_ranges(256, 64), 8)
+    store.set_column(2, 1.0, counters={"wait_s": 0.5})
+    ks = (3, 17, 64)
+    with jax.profiler.trace(str(tmp_path)):
+        store.time_matrix()
+        store.counter_columns("wait_s")
+        for k in ks:
+            for p in range(0, 4 * k, 4):
+                store.set_entry(p, 2, 0.25 * k, counters={"wait_s": 0.125})
+            for _ in range(2):
+                store.time_matrix()
+            for _ in range(2):
+                store.counter_columns("wait_s")
+    path = next(os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+                for f in fs if f.endswith(".xplane.pb"))
+    reads = sorted((ev.start_ns, dict(ev.stats))
+                   for plane in ProfileData.from_file(path).planes
+                   for line in plane.lines for ev in line.events
+                   if ev.name == "scalana.store.stack")
+    assert [st["restacked"] for _, st in reads] == \
+        [64, 64] + [n for k in ks for n in (k, 0, k, 0)]
+    assert all(st["shards"] == 64 for _, st in reads)
 
 
 def test_shared_block_scope_reaches_the_train_steps_hlo():
