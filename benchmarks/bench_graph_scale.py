@@ -18,24 +18,16 @@ scale:
     on CPU-only jax with host stores and is asserted within 2x of
     ``detect_numpy_s``); the explicit jitted timing is
     ``detect_jax_s`` (full run, post-warmup);
-  * ``detect_unfused_s`` vs ``detect_fused_s`` vs
-    ``detect_cached_steady_s`` (full run) — one device-fed detect cycle
-    (non-scalable over the series + abnormal at the top scale) through
-    the legacy multi-dispatch kernel chain (``fused=False``), the fused
-    one-launch ops with cold merged-column caches, and the steady state
-    (warm historical-scale cache, a 16-row dirty write on the live
-    scale); the steady state is asserted to be exactly 2 fused launches
-    (``detect_cached_launches``, via the launch-count seam) and >= 3x
-    faster than the unfused chain at the top scale;
-  * ``backtrack_s`` vs ``backtrack_batched_s`` — the scalar walk (the
-    "auto" default; frontier-batching is opt-in since it stopped winning
-    here, 0.62-1.12x) against the opt-in batched engine on a
-    many-straggler scenario (>= 256 flagged (proc, vertex) pairs at the
-    top scale); the paths are asserted identical, and the engines are
-    asserted to stay within a small factor of EACH OTHER at the top
-    scale — the scalar walk's per-step ``scanned | set(path)`` copy used
-    to go quadratic there (1.3s vs 0.11s batched at 8192), fixed by the
-    non-copying union view in ``backtrack_one``;
+  * ``detect_fused_s`` vs ``detect_cached_steady_s`` (full run) — one
+    device-fed detect cycle (non-scalable over the series + abnormal at
+    the top scale) through the fused one-launch ops with cold
+    merged-column caches, and the steady state (warm historical-scale
+    cache, a 16-row dirty write on the live scale); the steady state is
+    asserted to be exactly 2 fused launches (``detect_cached_launches``,
+    via the launch-count seam);
+  * ``backtrack_s`` — the backtracking walk on a many-straggler
+    scenario (>= 256 flagged (proc, vertex) pairs at the top scale,
+    asserted);
   * ``shard_merge_s`` — merging an 8-host sharded replay
     (``simulate(..., shards=8)``) into one store through
     ``PerfStore.from_shards`` (contiguous fresh ranges take the
@@ -642,11 +634,10 @@ def run(smoke: bool = False) -> List[Dict]:
         rcs = root_causes(paths, psg, ppg=top)
         pipeline_backtrack_s = time.perf_counter() - t0
 
-        # -- frontier-batched backtracking vs the scalar reference -------
+        # -- backtracking over many stragglers ---------------------------
         # many distinct stragglers at a mid-chain comp vertex: hundreds of
         # flagged (proc, vertex) pairs whose causal walks are long and
-        # disjoint — the regime Algorithm 1 faces at scale, where the
-        # scalar walk's per-step scanned-set copies go quadratic
+        # disjoint — the regime Algorithm 1 faces at scale
         comps = [v.vid for v in psg.vertices if v.kind == COMP]
         mid = comps[len(comps) // 2]
 
@@ -661,31 +652,11 @@ def run(smoke: bool = False) -> List[Dict]:
         res_bt = simulate(psg, n_procs, straggle)
         ab_bt = detect_abnormal(res_bt.ppg, top_k=4096, backend="numpy")
         t0 = time.perf_counter()
-        paths_scalar = backtrack(res_bt.ppg, [], ab_bt, mode="scalar")
+        backtrack(res_bt.ppg, [], ab_bt)
         backtrack_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        paths_batched = backtrack(res_bt.ppg, [], ab_bt, mode="batched")
-        backtrack_batched_s = time.perf_counter() - t0
-        assert [(p.nodes, p.start_reason) for p in paths_scalar] == \
-            [(p.nodes, p.start_reason) for p in paths_batched], \
-            "batched and scalar backtracking disagree"
-        backtrack_speedup = backtrack_s / max(backtrack_batched_s, 1e-12)
         if not smoke and n_procs == max(scales):
             assert len(ab_bt) >= 256, \
                 f"backtrack scenario flagged only {len(ab_bt)} pairs"
-            # the scalar walk (the "auto" default since batched was
-            # demoted — it wins or ties at 0.62-1.12x here) used to go
-            # quadratic in its per-step `scanned | set(path)` copy (1.3s
-            # vs 0.11s batched at 8192/512 flagged); the union-view fix
-            # keeps the two engines within a small factor of each other,
-            # and a regression in EITHER direction fails this
-            assert backtrack_s <= 3.0 * backtrack_batched_s + 0.05, \
-                f"scalar backtrack quadratic again? {backtrack_s:.3f}s vs " \
-                f"batched {backtrack_batched_s:.3f}s at {n_procs} procs " \
-                f"({len(ab_bt)} flagged)"
-            assert backtrack_batched_s <= 3.0 * backtrack_s + 0.05, \
-                f"batched backtrack regressed? {backtrack_batched_s:.3f}s " \
-                f"vs scalar {backtrack_s:.3f}s at {n_procs} procs"
 
         # -- streamed shard merge ---------------------------------------
         res_sh = simulate(psg, n_procs, straggle, shards=8)
@@ -738,16 +709,14 @@ def run(smoke: bool = False) -> List[Dict]:
 
         # -- fused one-launch detection + historical-scale cache ---------
         # one full device-fed detect CYCLE (non-scalable over the series
-        # + abnormal at the top scale), three ways: the legacy unfused
-        # kernel chain (fused=False — what every call paid before), the
-        # fused ops with cold merged-column caches (a first call), and
-        # the steady state — warm caches, a 16-row dirty write on the
-        # live scale, exactly 2 fused launches (asserted via the
-        # launch-count seam, not inferred)
-        detect_unfused_s = detect_fused_s = detect_cached_steady_s = 0.0
+        # + abnormal at the top scale), two ways: the fused ops with cold
+        # merged-column caches (a first call), and the steady state —
+        # warm caches, a 16-row dirty write on the live scale, exactly 2
+        # fused launches (asserted via the launch-count seam, not
+        # inferred)
+        detect_fused_s = detect_cached_steady_s = 0.0
         detect_cached_launches = 0
         if detect_backend == "jax":
-            from repro.core import detect_jax
             from repro.kernels.detect_fused import ops as fused_ops
 
             def _time_at_scale(n):
@@ -766,34 +735,13 @@ def run(smoke: bool = False) -> List[Dict]:
                                      shards=min(8, n)).ppg
                          for n in series_scales}
             top_sh = series_sh[n_procs]
-            sc = sorted(series_sh)
-            top_children = psg.children(psg.root)
-            present = np.ones((len(sc), V), bool)  # one psg, all scales
-            views = [series_sh[n].device_view() for n in sc]
-
-            def legacy_cycle():
-                ns_v = detect_jax.non_scalable_views(
-                    sc, views, V, present, top_children, -1.0, 0.35,
-                    0.02, "mean", fused=False)
-                ab_v = detect_jax.abnormal_topk_view(
-                    top_sh.device_view(), V, top_children, 1.3, 0.01,
-                    20, fused=False)
-                return ns_v, ab_v
+            views = [series_sh[n].device_view() for n in sorted(series_sh)]
 
             def fused_cycle():
                 return (detect_non_scalable(series_sh, backend="jax"),
                         detect_abnormal(top_sh, backend="jax"))
 
-            ns_f, ab_f = fused_cycle()          # warm fused + fill caches
-            ns_l, ab_l = legacy_cycle()         # warm the legacy chain
-            assert [(a.proc, a.vid) for a in ab_f] == \
-                [(int(p), int(v)) for v, p in zip(*ab_l[:2])], \
-                "fused and legacy device detection disagree"
-
-            t0 = time.perf_counter()
-            legacy_cycle()
-            detect_unfused_s = time.perf_counter() - t0
-
+            fused_cycle()                       # warm fused + fill caches
             for v in views[:-1]:                # cold caches: a 1st call
                 v.cache_merged_column(None)
             t0 = time.perf_counter()
@@ -813,11 +761,6 @@ def run(smoke: bool = False) -> List[Dict]:
                 {"non_scalable_live": 1, "abnormal": 1}, \
                 f"steady-state detect not 2 fused launches: " \
                 f"{dict(fused_ops.launch_counts)}"
-            if not smoke and n_procs == max(scales):
-                assert detect_cached_steady_s * 3.0 <= detect_unfused_s, \
-                    f"cached fused detect not >=3x the unfused chain: " \
-                    f"{detect_cached_steady_s:.4f}s vs " \
-                    f"{detect_unfused_s:.4f}s at {n_procs} procs"
 
         # -- always-on monitor: steady-state ingest -> detect latency ----
         # per-host producers stream full-row deltas into a resident
@@ -866,14 +809,11 @@ def run(smoke: bool = False) -> List[Dict]:
             "detect_jax_s": detect_jax_s,
             "pipeline_backtrack_s": pipeline_backtrack_s,
             "backtrack_s": backtrack_s,
-            "backtrack_batched_s": backtrack_batched_s,
-            "backtrack_speedup": backtrack_speedup,
             "backtrack_flagged": len(ab_bt),
             "shard_merge_s": shard_merge_s,
             "shard_hosts": len(res_sh.shards),
             "detect_device_s": detect_device_s,
             "detect_host_fed_s": detect_host_fed_s,
-            "detect_unfused_s": detect_unfused_s,
             "detect_fused_s": detect_fused_s,
             "detect_cached_steady_s": detect_cached_steady_s,
             "detect_cached_launches": detect_cached_launches,
@@ -905,13 +845,10 @@ def run(smoke: bool = False) -> List[Dict]:
              f"detect_backend={detect_backend};detect_numpy_s="
              f"{detect_np_s:.4f};detect_jax_s={detect_jax_s:.4f};"
              f"backtrack_s={backtrack_s:.3f};"
-             f"backtrack_batched_s={backtrack_batched_s:.4f};"
-             f"backtrack_speedup={backtrack_speedup:.1f};"
              f"backtrack_flagged={len(ab_bt)};"
              f"shard_merge_s={shard_merge_s:.4f};"
              f"detect_device_s={detect_device_s:.4f};"
              f"detect_host_fed_s={detect_host_fed_s:.4f};"
-             f"detect_unfused_s={detect_unfused_s:.4f};"
              f"detect_fused_s={detect_fused_s:.4f};"
              f"detect_cached_steady_s={detect_cached_steady_s:.4f};"
              f"detect_cached_launches={detect_cached_launches};"

@@ -14,8 +14,7 @@ from typing import TYPE_CHECKING
 # export name -> submodule that defines it
 _EXPORTS = {
     "Path": "backtrack", "backtrack": "backtrack",
-    "backtrack_batched": "backtrack", "backtrack_one": "backtrack",
-    "backtrack_scalar": "backtrack", "root_causes": "backtrack",
+    "backtrack_one": "backtrack", "root_causes": "backtrack",
     "CommLog": "commdep", "add_comm_edges": "commdep",
     "annotate_from_hlo": "commdep",
     "contract": "contraction",
@@ -69,8 +68,7 @@ from repro.core.backtrack import backtrack  # noqa: E402
 
 
 if TYPE_CHECKING:                     # static analyzers see eager imports
-    from repro.core.backtrack import (Path, backtrack, backtrack_batched,
-                                      backtrack_one, backtrack_scalar,
+    from repro.core.backtrack import (Path, backtrack, backtrack_one,
                                       root_causes)
     from repro.core.commdep import CommLog, add_comm_edges, annotate_from_hlo
     from repro.core.contraction import contract

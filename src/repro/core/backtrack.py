@@ -17,24 +17,10 @@ problematic vertex we walk backward:
 The result is a set of causal paths over (process, vertex) pairs whose
 endpoints are the root-cause candidates, reported with source locations.
 
-Two engines produce identical paths:
-
-* the scalar walk (``backtrack_scalar`` / ``backtrack_one``) — a direct
-  transcription of Algorithm 1, retained as the property-tested reference;
-* the frontier-batched walk (``backtrack_batched``, the default) — ALL
-  flagged (proc, vertex) start nodes advance in lockstep, one step per
-  iteration: data-dependence predecessors for the whole frontier are one
-  padded gather + argmax over the time matrix, collective late-arriver
-  lookups are one cached per-vertex argmin over the participant group
-  (``CommIndex``), and waiting-p2p partners resolve through the explicit
-  reverse-edge index.  Algorithm 1's sequential ``scanned``-set semantics
-  (earlier paths prune later ones) are restored afterwards by an
-  acceptance pass: paths are admitted in start order, and any path whose
-  nodes — or whose branch-deciding probe nodes — touch an already-scanned
-  node is recomputed with the scalar walk against the true scanned set.
-  Disjoint paths (the overwhelmingly common case) keep their batched
-  result, so root-cause detection at 8k processes with hundreds of
-  flagged vertices is no longer bound by per-node Python scans.
+One engine walks them: :func:`backtrack` runs Algorithm 1 Main(), one
+sequential :func:`backtrack_one` walk per start node, in start order.
+Each walk skips nodes already scanned by an earlier path (the paper's
+``scanned`` set), so overlapping causal paths are walked once.
 """
 from __future__ import annotations
 
@@ -44,7 +30,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 import numpy as np
 
 from repro.core.detect import Abnormal, NonScalable
-from repro.core.graph import BRANCH, CALL, COMM, LOOP, PPG, PSG, ROOT
+from repro.core.graph import BRANCH, CALL, COMM, LOOP, PPG, PSG
 from repro.core.spans import spanned
 
 Node = Tuple[int, int]                     # (proc, vid)
@@ -71,9 +57,9 @@ class _SeenUnion:
 
     ``backtrack_one`` used to rebuild ``scanned | set(path)`` on every
     step — an O(|scanned| + |path|) copy per node that goes quadratic
-    when hundreds of conflicting paths fall back to the scalar walk over
-    a large scanned set.  The walk only ever asks ``node in visited``, so
-    a chained-membership wrapper over the two live sets (the path set
+    when hundreds of overlapping paths are walked over a large scanned
+    set.  The walk only ever asks ``node in visited``, so a
+    chained-membership wrapper over the two live sets (the path set
     updated incrementally on append) is semantically identical and O(1)
     per probe."""
 
@@ -144,7 +130,7 @@ def backtrack_one(ppg: PPG, start: Node, *, reason: str,
     path: List[Node] = []
     path_set: Set[Node] = set()
     # visited == scanned | set(path) at every step, without the per-step
-    # union copy (quadratic over many conflicting scalar-fallback paths)
+    # union copy (quadratic over many overlapping paths)
     visited = _SeenUnion(scanned, path_set)
     v: Optional[Node] = start
     first = True
@@ -194,11 +180,11 @@ def _start_nodes(ppg: PPG, non_scalable: Sequence[NonScalable],
     return starts
 
 
-def backtrack_scalar(ppg: PPG, non_scalable: Sequence[NonScalable],
-                     abnormal: Sequence[Abnormal]) -> List[Path]:
-    """Algorithm 1 Main(), one sequential scalar walk per start node: the
-    retained reference implementation (``backtrack_batched`` must — and is
-    property-tested to — return exactly these paths)."""
+@spanned("backtrack")
+def backtrack(ppg: PPG, non_scalable: Sequence[NonScalable],
+              abnormal: Sequence[Abnormal]) -> List[Path]:
+    """Algorithm 1 Main(): non-scalable starts first, then unscanned
+    abnormal vertices, one sequential scalar walk per start node."""
     scanned: Set[Node] = set()
     paths: List[Path] = []
     for node, reason in _start_nodes(ppg, non_scalable, abnormal):
@@ -208,280 +194,6 @@ def backtrack_scalar(ppg: PPG, non_scalable: Sequence[NonScalable],
         if p.nodes:
             paths.append(p)
     return paths
-
-
-# ---------------------------------------------------------------------------
-# frontier-batched walk
-# ---------------------------------------------------------------------------
-
-# per-vertex walk categories (process-independent, computed once per call)
-_K_ROOT, _K_COLL, _K_P2P, _K_CTRL, _K_DATA = range(5)
-
-
-class _Frontier:
-    """Array context for the batched walk: the time/wait matrices, padded
-    data-predecessor table, per-vertex category codes, and a lazy cache of
-    per-collective late-arriver lookups (one vectorized argmin over the
-    participant group per vertex, shared by every path that reaches it)."""
-
-    __slots__ = ("ppg", "psg", "T", "W", "kcode", "PRED", "_late")
-
-    def __init__(self, ppg: PPG):
-        self.ppg = ppg
-        self.psg = psg = ppg.psg
-        V = len(psg.vertices)
-        self.T = ppg.times_matrix()
-        self.W = _wait_matrix(ppg)
-        kcode = np.full(V, _K_DATA, np.int8)
-        for v in psg.vertices:
-            if v.kind == ROOT:
-                kcode[v.vid] = _K_ROOT
-            elif v.kind == COMM:
-                kcode[v.vid] = _K_P2P if v.p2p_pairs else _K_COLL
-            elif v.kind in (LOOP, BRANCH, CALL):
-                kcode[v.vid] = _K_CTRL
-        self.kcode = kcode
-        plists = [psg.preds(v.vid, "data") for v in psg.vertices]
-        kp = max((len(p) for p in plists), default=1) or 1
-        self.PRED = np.full((V, kp), -1, np.intp)
-        for vid, ps in enumerate(plists):
-            self.PRED[vid, :len(ps)] = ps
-        self._late: Dict[int, Tuple] = {}
-
-    def late_info(self, vid: int) -> Tuple:
-        """Cached late-arriver lookup for one collective vertex.
-
-        Returns ("map", gid_of, per_group): ``gid_of`` maps proc -> group
-        index (-1: not a participant) and ``per_group[gid]`` is the
-        group's (first_min_wait_proc, second_min_wait_proc | None) — one
-        vectorized argmin over each participant group, shared by every
-        path that reaches the vertex.  ("none", ...) when the vertex has
-        no groups; ("complex", ...) when groups overlap or name unknown
-        procs (those paths fall back to the scalar walk)."""
-        info = self._late.get(vid)
-        if info is None:
-            groups = self.ppg.comm.groups_of(vid)
-            if not groups:
-                info = ("none", None, None)
-            else:
-                gid_of = np.full(self.ppg.n_procs, -1, np.intp)
-                per: List[Tuple[int, Optional[int]]] = []
-                info = None
-                for gi, g in enumerate(groups):
-                    garr = np.asarray(g, np.intp)
-                    if garr.size and (garr.min() < 0
-                                      or garr.max() >= gid_of.size) \
-                            or (gid_of[garr] != -1).any():
-                        info = ("complex", None, None)
-                        break
-                    gid_of[garr] = gi
-                    w = self.W[garr, vid]
-                    m = w.min()
-                    firsts = np.flatnonzero(w == m)
-                    q1 = int(garr[firsts[0]])
-                    q2 = int(garr[firsts[1]]) if firsts.size > 1 else None
-                    per.append((q1, q2))
-                if info is None:
-                    info = ("map", gid_of, per)
-            self._late[vid] = info
-        return info
-
-
-def backtrack_batched(ppg: PPG, non_scalable: Sequence[NonScalable],
-                      abnormal: Sequence[Abnormal], *,
-                      max_len: int = 256) -> List[Path]:
-    """Frontier-batched Algorithm 1: identical paths to
-    :func:`backtrack_scalar`, computed by advancing every start node in
-    lockstep over array gathers (see the module docstring).
-
-    Batched paths exclude only their OWN nodes while walking; the
-    sequential cross-path pruning is restored by the acceptance pass
-    below, which recomputes — with the exact scalar walk — any path that
-    touched a node (or probed a late-arriver) already scanned by an
-    earlier path.  A selector over candidates not in ``scanned | path``
-    picks the same node as one over candidates not in ``path`` whenever
-    the pick is unscanned, so untouched batched paths are exact.
-    """
-    starts = _start_nodes(ppg, non_scalable, abnormal)
-    N = len(starts)
-    if N == 0:
-        return []
-    ctx = _Frontier(ppg)
-    comm = ppg.comm
-    paths: List[List[Node]] = [[] for _ in range(N)]
-    probes: List[List[Node]] = [[] for _ in range(N)]
-    visited: List[Set[Node]] = [set() for _ in range(N)]
-    conflict = np.zeros(N, bool)
-    cur_p = np.fromiter((s[0][0] for s in starts), np.intp, N)
-    cur_v = np.fromiter((s[0][1] for s in starts), np.intp, N)
-    alive = np.ones(N, bool)
-    first = np.ones(N, bool)
-
-    while alive.any():
-        idx = np.nonzero(alive)[0]
-        lens = np.fromiter((len(paths[i]) for i in idx), np.intp, idx.size)
-        over = lens >= max_len
-        if over.any():
-            alive[idx[over]] = False
-            idx = idx[~over]
-            if idx.size == 0:
-                break
-        vs, ps = cur_v[idx], cur_p[idx]
-        kc = ctx.kcode[vs]
-        mroot = kc == _K_ROOT
-        alive[idx[mroot]] = False
-        mterm = (kc == _K_COLL) & ~first[idx]
-        for i, p, v in zip(idx[mterm].tolist(), ps[mterm].tolist(),
-                           vs[mterm].tolist()):
-            paths[i].append((p, v))             # terminal collective
-            alive[i] = False
-        live = ~mroot & ~mterm
-        lidx, lps, lvs, lkc = idx[live], ps[live], vs[live], kc[live]
-        for i, p, v in zip(lidx.tolist(), lps.tolist(), lvs.tolist()):
-            paths[i].append((p, v))
-            visited[i].add((p, v))
-
-        # -- choose the next node per path ------------------------------
-        # data-pred requests accumulate and resolve in ONE padded
-        # gather+argmax over the time matrix for the whole frontier
-        nxt: List[Optional[Node]] = [None] * lidx.size
-        req: List[Tuple[int, int, int, Optional[Node]]] = []
-        for k in range(lidx.size):
-            i = int(lidx[k])
-            p, v, code = int(lps[k]), int(lvs[k]), int(lkc[k])
-            if code == _K_COLL:                 # collective start vertex
-                tag, gid_of, per = ctx.late_info(v)
-                if tag == "complex" or comm.p2p_preds_of((p, v)):
-                    conflict[i] = True          # scalar walk handles it
-                    alive[i] = False
-                    continue
-                late: Optional[Node] = None
-                if tag == "map" and gid_of[p] >= 0:
-                    q1, q2 = per[gid_of[p]]
-                    lp = q1 if q1 != p else (q2 if q2 is not None else p)
-                    late = (lp, v)
-                    if late != (p, v):
-                        probes[i].append(late)  # scanned-sensitive branch
-                if late is not None and late not in visited[i]:
-                    req.append((k, late[0], v, late))   # pred-of-late|late
-                else:
-                    req.append((k, p, v, None))         # pred-of-v | stop
-            elif code == _K_P2P:
-                chosen = None
-                if ctx.W[p, v] > WAIT_EPS:      # pruning: waiting edges only
-                    if comm.has_groups(v):
-                        conflict[i] = True
-                        alive[i] = False
-                        continue
-                    best_t = -np.inf
-                    for q in comm.p2p_preds_of((p, v)):
-                        if q in visited[i]:
-                            continue
-                        tq = ctx.T[q[0], q[1]]
-                        if tq > best_t:
-                            chosen, best_t = q, tq
-                if chosen is not None:
-                    nxt[k] = chosen
-                else:
-                    req.append((k, p, v, None))
-            elif code == _K_CTRL:               # continue from structure end
-                chosen = None
-                for c in reversed(ctx.psg.children(v)):
-                    if (p, c) not in visited[i]:
-                        chosen = (p, c)
-                        break
-                if chosen is not None:
-                    nxt[k] = chosen
-                else:
-                    req.append((k, p, v, None))
-            else:
-                req.append((k, p, v, None))
-
-        if req:
-            rp = np.fromiter((r[1] for r in req), np.intp, len(req))
-            rv = np.fromiter((r[2] for r in req), np.intp, len(req))
-            cand = ctx.PRED[rv]                             # (M, Kp)
-            valid = cand >= 0
-            t = np.where(valid,
-                         ctx.T[rp[:, None], np.where(valid, cand, 0)],
-                         -np.inf)
-            ji = np.argmax(t, axis=1)                       # first max
-            has = valid[np.arange(len(req)), ji]
-            for m, (k, _, _, fallback) in enumerate(req):
-                i = int(lidx[k])
-                if not alive[i]:
-                    continue
-                chosen = None
-                if has[m]:
-                    node = (int(rp[m]), int(cand[m, ji[m]]))
-                    if node not in visited[i]:
-                        chosen = node
-                    else:      # rare: rescan candidates minus own path
-                        best_t = -np.inf
-                        for c in cand[m][valid[m]].tolist():
-                            node = (int(rp[m]), int(c))
-                            if node in visited[i]:
-                                continue
-                            tc = ctx.T[node[0], c]
-                            if tc > best_t:
-                                chosen, best_t = node, tc
-                if chosen is None and fallback is not None \
-                        and fallback not in visited[i]:
-                    chosen = fallback                       # the `or late`
-                nxt[k] = chosen
-
-        for k in range(lidx.size):
-            i = int(lidx[k])
-            if not alive[i]:
-                continue
-            node = nxt[k]
-            if node is None:
-                alive[i] = False
-            else:
-                cur_p[i], cur_v[i] = node
-        first[idx] = False
-
-    # -- acceptance: restore the sequential scanned-set semantics -------
-    scanned: Set[Node] = set()
-    out: List[Path] = []
-    for j, (node, reason) in enumerate(starts):
-        if reason == "abnormal" and node in scanned:
-            continue
-        if conflict[j] or any(n in scanned for n in paths[j]) \
-                or any(q in scanned for q in probes[j]):
-            p = backtrack_one(ppg, node, reason=reason, scanned=scanned,
-                              max_len=max_len)
-        else:
-            p = Path(nodes=paths[j], start_reason=reason)
-            scanned.update(paths[j])
-        if p.nodes:
-            out.append(p)
-    return out
-
-
-BACKTRACK_MODES = ("auto", "batched", "scalar")
-
-
-@spanned("backtrack")
-def backtrack(ppg: PPG, non_scalable: Sequence[NonScalable],
-              abnormal: Sequence[Abnormal], *,
-              mode: str = "auto") -> List[Path]:
-    """Algorithm 1 Main(): non-scalable starts first, then unscanned
-    abnormal vertices.
-
-    ``mode``: "scalar" (the per-start reference walk), "batched" (the
-    frontier-batched engine, opt-in), or "auto" (default — scalar).
-    Batched was the "auto" pick while the scalar walk's per-step
-    scanned-set copies went quadratic; with the non-copying union view
-    the scalar walk wins or ties across BENCH_graph_scale.json
-    (0.62-1.12x), so the simpler engine is the default and batched is
-    kept for workloads with very many long disjoint walks.  All modes
-    return identical paths."""
-    if mode not in BACKTRACK_MODES:
-        raise ValueError(f"mode must be one of {BACKTRACK_MODES}: {mode!r}")
-    if mode == "batched":
-        return backtrack_batched(ppg, non_scalable, abnormal)
-    return backtrack_scalar(ppg, non_scalable, abnormal)
 
 
 def _anomaly_score(ppg: PPG, node: Node,

@@ -21,8 +21,7 @@ on each detector selects it explicitly ("numpy" / "jax"); the default
 "auto" uses the jitted path only when jax is ALREADY imported in the
 process, so the pure-numpy analysis layer never pays the jax import (the
 jax-free ``--smoke`` canary stays jax-free); on an accelerator it always
-takes the device path, and fails loudly if that path cannot load.  The
-``SCALANA_DETECT_BACKEND`` environment variable overrides the default.
+takes the device path, and fails loudly if that path cannot load.
 
 Merge strategies (``MERGE_STRATEGIES``): "mean", "median", "max", "p0",
 "cluster", and variance-weighted "var" (readings weighted 1/time_var —
@@ -33,7 +32,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import os
 import sys
 import warnings
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
@@ -64,20 +62,16 @@ def _resolve_backend(backend: Optional[str], device_live: bool = False):
     sharded store feeding the zero-copy DeviceShardView path) or a
     non-CPU accelerator is the default jax backend.  On CPU-only jax with
     host-side stores the dispatch overhead makes the jitted path ~10x
-    slower than numpy, so auto stays on numpy there; "jax" (explicitly or
-    via SCALANA_DETECT_BACKEND) still forces the jitted path, and
-    "numpy" never touches jax.  Once the jitted path is chosen, a
-    device path that cannot be imported raises: on an accelerator a
-    silent numpy fallback would hide the device.
+    slower than numpy, so auto stays on numpy there; "jax" still forces
+    the jitted path, and "numpy" never touches jax; ``None`` is "auto".
+    Once the jitted path is chosen, a device path that cannot be
+    imported raises: on an accelerator a silent numpy fallback would
+    hide the device.
     """
-    from_env = backend is None
-    if from_env:
-        backend = os.environ.get("SCALANA_DETECT_BACKEND", "auto")
-    backend = str(backend).strip().lower()
+    backend = "auto" if backend is None else str(backend).strip().lower()
     if backend not in ("numpy", "jax", "auto"):
-        origin = " (from SCALANA_DETECT_BACKEND)" if from_env else ""
         raise ValueError(
-            f"unknown detect backend{origin}: {backend!r}; valid values "
+            f"unknown detect backend: {backend!r}; valid values "
             f"are 'numpy', 'jax', 'auto'")
     if backend == "numpy":
         return None

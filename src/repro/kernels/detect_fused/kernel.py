@@ -22,10 +22,10 @@ Two kernels cover the whole detection tail in one launch each:
   through VMEM scratch) that reproduces the reference ranking exactly:
   descending score, ties broken by ascending vid-major flat index.
 
-The pure-jnp merge/slope/flag formulas shared by the legacy stacked
-kernels (``repro.core.detect_jax``), the fused jnp fast path
-(``ops.py``), and the kernel bodies themselves are defined at the top of
-this module — single source of truth, so the three paths cannot drift.
+The pure-jnp merge/slope/flag formulas shared by the fused jnp fast
+path (``ops.py``) and the kernel bodies themselves are defined at the
+top of this module — single source of truth, so the two paths cannot
+drift.
 
 Everything is dtype-generic over f32/f64 (the TPU runs f32, see
 ``repro.core.detect_jax.precision``); the float<->ordered-integer key
@@ -56,8 +56,8 @@ _STEP_EPS = 1e-12         # step-time clamp, matches the host reference
 _AB_VMEM_LIMIT = 100 * 2 ** 20
 
 
-# -- shared detection math (jnp; used by legacy kernels, fused jnp path,
-# -- and inside the Pallas kernel bodies) -------------------------------
+# -- shared detection math (jnp; used by the fused jnp path and inside
+# -- the Pallas kernel bodies) ------------------------------------------
 
 def merge_all_stack(t: jax.Array, var: jax.Array) -> jax.Array:
     """(S, P, V) times + variances -> (4, S, V) merged, rows ordered as

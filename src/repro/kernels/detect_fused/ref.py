@@ -2,10 +2,8 @@
 
 Independent re-derivation of the detection math from
 ``repro.core.detect``'s numpy path, in the exact shapes the fused
-kernels consume, so the parity tests pin three implementations against
-each other: this reference, the legacy stacked-jnp kernels in
-``repro.core.detect_jax``, and the fused kernels (both the jnp fast
-path and the Pallas interpret mode).
+kernels consume, so the parity tests pin the fused kernels (both the
+jnp fast path and the Pallas interpret mode) against this reference.
 
 Everything here is host numpy and float64 unless the caller passes
 other dtypes; nothing imports jax.
@@ -102,7 +100,7 @@ def abnormal_ref(t: np.ndarray, top: Sequence[int], abnorm_thd: float,
     the degraded path); ``valid`` marks real rows (None = all live).
     ``order`` are flat vid-major indices (``vid * P + proc``) of the top
     ``k`` scoring entries, ranked by descending ``time - typical`` with
-    stable ascending-index ties — exactly the legacy kernel contract.
+    stable ascending-index ties — exactly the numpy detector's ranking.
     """
     P, V = t.shape
     if valid is None:

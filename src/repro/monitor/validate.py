@@ -1,7 +1,7 @@
 """Constructor-knob validation shared across the monitor stack.
 
 Every check raises ``ValueError`` naming the offending argument and the
-value it got (mirroring the ``SCALANA_DETECT_BACKEND`` style in
+value it got (mirroring the unknown-``backend=`` error in
 ``repro.core.detect``), so a mistyped knob fails at construction with a
 message that says which knob — not three layers down with an opaque
 type error.

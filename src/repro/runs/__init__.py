@@ -13,8 +13,8 @@ The pieces:
   whose graphs drifted, by stable (kind, name, path-from-root)
   signatures with explicit added/removed sets — never positionally.
 * :func:`~repro.runs.diff.diff_runs` — per-vertex scaling-curve deltas
-  and regression flags, reusing the detect slope machinery (numpy and
-  jax backends behind ``detect._resolve_backend``).
+  and regression flags, reusing the detect slope machinery (its
+  ``backend=`` picks numpy or jax exactly as detection's does).
 * :func:`~repro.runs.cluster.cluster_procs` — groups processes by
   behavior vector (per-vertex time + counter signature) so an 8k–64k
   proc run stores and diffs as K representatives + a membership map.

@@ -164,8 +164,8 @@ def diff_runs(base: RunRecord, cand: RunRecord, *,
     to ``ratio_thd`` and only applies when both runs were recorded at
     the same scale with a stored PPG.
     ``top_k`` > 0 truncates the flagged regression list; 0 keeps all.
-    ``backend`` routes the slope fits exactly like detection's knob
-    ("numpy" / "jax" / "auto" / None -> SCALANA_DETECT_BACKEND)."""
+    ``backend`` routes the slope fits exactly like detection's
+    ``backend=`` ("numpy" / "jax" / "auto"; None means "auto")."""
     if base.psg is None or cand.psg is None:
         raise ValueError("both runs need a stored PSG to diff")
     alignment = align_psgs(base.psg, cand.psg)

@@ -1,11 +1,9 @@
 """Backtracking root-cause detection (Algorithm 1): the paper's core."""
 import numpy as np
-import pytest
 
 from repro.core import (COMM, COMP, PSG, backtrack, build_ppg,
                         detect_abnormal, detect_non_scalable, root_causes)
-from repro.core.backtrack import (WAIT_COUNTER, backtrack_batched,
-                                  backtrack_one, backtrack_scalar)
+from repro.core.backtrack import WAIT_COUNTER, backtrack_one
 from repro.core.graph import PerfVector
 from repro.core.inject import simulate, simulate_series
 
@@ -147,8 +145,27 @@ def _random_psg(rng, n_procs):
     return g
 
 
-def test_batched_equals_scalar_on_random_ppgs():
-    """The frontier-batched walk returns EXACTLY the scalar reference's
+def _main_copying(ppg, non_scalable, abnormal):
+    """Algorithm 1 Main() over the copying reference walk: non-scalable
+    vertices first, each from its slowest process, then the abnormal
+    (proc, vertex) pairs no earlier path has scanned."""
+    tm = ppg.times_matrix()
+    starts = [((int(tm[:, n.vid].argmax()) if tm.size else 0, n.vid),
+               "non_scalable") for n in non_scalable]
+    starts += [((a.proc, a.vid), "abnormal") for a in abnormal]
+    scanned, paths = set(), []
+    for node, reason in starts:
+        if reason == "abnormal" and node in scanned:
+            continue
+        p = _backtrack_one_copying(ppg, node, reason=reason,
+                                   scanned=scanned)
+        if p.nodes:
+            paths.append(p)
+    return paths
+
+
+def test_backtrack_equals_copying_reference_on_random_ppgs():
+    """``backtrack`` returns EXACTLY the copying reference Main()'s
     paths — overlapping starts, ties (jitter-free waits), grouped and
     global collectives, p2p chains, loops and diamonds included."""
     rng = np.random.default_rng(42)
@@ -169,13 +186,13 @@ def test_batched_equals_scalar_on_random_ppgs():
                                  lambda p, vid, n: 0.02 * (0.5 + 0.5 / n),
                                  seed=trial)
         ns = detect_non_scalable(series, min_share=0.0, top_k=20)
-        assert _paths_key(backtrack_batched(res.ppg, ns, ab)) == \
-            _paths_key(backtrack_scalar(res.ppg, ns, ab)), trial
+        assert _paths_key(backtrack(res.ppg, ns, ab)) == \
+            _paths_key(_main_copying(res.ppg, ns, ab)), trial
 
 
-def test_batched_equals_scalar_overlapping_straggler_block():
-    """Many starts flagged at the SAME vertices: the acceptance pass must
-    reproduce the sequential scanned-set pruning exactly."""
+def test_backtrack_equals_copying_reference_overlapping_straggler_block():
+    """Many starts flagged at the SAME vertices: the shared scanned set
+    must prune later paths exactly as the sequential reference does."""
     rng = np.random.default_rng(7)
     for trial in range(10):
         n_procs = 12
@@ -185,8 +202,8 @@ def test_batched_equals_scalar_overlapping_straggler_block():
         res = simulate(g, n_procs, lambda p, vid_: 0.01, inject=inj,
                        seed=trial)
         ab = detect_abnormal(res.ppg, top_k=500)
-        assert _paths_key(backtrack_batched(res.ppg, [], ab)) == \
-            _paths_key(backtrack_scalar(res.ppg, [], ab)), trial
+        assert _paths_key(backtrack(res.ppg, [], ab)) == \
+            _paths_key(_main_copying(res.ppg, [], ab)), trial
 
 
 def test_backtrack_export_survives_submodule_import():
@@ -198,17 +215,6 @@ def test_backtrack_export_survives_submodule_import():
     importlib.import_module("repro.core.backtrack")
     from repro.core import backtrack as fn
     assert callable(fn) and fn is sys.modules["repro.core.backtrack"].backtrack
-
-
-def test_backtrack_mode_dispatch():
-    g, (c0, c1, p2p, c2, ar) = _pipeline_psg()
-    res = simulate(g, 8, lambda p, vid: 0.01, inject={(4, c0): 0.5})
-    ab = detect_abnormal(res.ppg)
-    keys = {mode: _paths_key(backtrack(res.ppg, [], ab, mode=mode))
-            for mode in ("auto", "batched", "scalar")}
-    assert keys["auto"] == keys["batched"] == keys["scalar"]
-    with pytest.raises(ValueError):
-        backtrack(res.ppg, [], ab, mode="nope")
 
 
 def test_non_scalable_plus_backtrack_end_to_end():

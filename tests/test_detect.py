@@ -144,29 +144,29 @@ def test_unknown_backend_raises_with_valid_values_listed():
         detect_abnormal(ppg, backend="numpy")
 
 
-def test_env_backend_validated_and_attributed(monkeypatch):
+def test_env_backend_validated_and_attributed():
+    """An unknown ``backend=`` raises naming the value; ``None`` means
+    "auto" and resolves exactly like it."""
     psg = _linear_psg()
     perf = {p: {v.vid: PerfVector(time=0.1) for v in psg.vertices
                 if v.kind == COMP} for p in range(4)}
     ppg = build_ppg(psg, 4, perf)
-    monkeypatch.setenv("SCALANA_DETECT_BACKEND", "cuda")
     with pytest.raises(ValueError,
-                       match=r"\(from SCALANA_DETECT_BACKEND\): 'cuda'"):
-        detect_abnormal(ppg)
-    monkeypatch.setenv("SCALANA_DETECT_BACKEND", "numpy")
-    detect_abnormal(ppg)                       # valid value passes through
+                       match=r"unknown detect backend: 'cuda'"):
+        detect_abnormal(ppg, backend="cuda")
+    assert detect_abnormal(ppg, backend=None) == \
+        detect_abnormal(ppg, backend="auto")
 
 
-def test_auto_backend_prefers_numpy_on_cpu_only_jax(monkeypatch):
+def test_auto_backend_prefers_numpy_on_cpu_only_jax():
     """Merely having jax importable must no longer flip auto onto the
     jitted path: on CPU-only jax with host-side stores the dispatch
     overhead makes it ~10x slower than numpy.  auto picks jax only when
     the data is device-resident (device_live) or a real accelerator is
-    the default backend; explicit 'jax' (arg or env) still forces it."""
+    the default backend; an explicit 'jax' still forces it."""
     jax = pytest.importorskip("jax")
     from repro.core.detect import _resolve_backend
 
-    monkeypatch.delenv("SCALANA_DETECT_BACKEND", raising=False)
     if jax.default_backend() != "cpu":
         pytest.skip("accelerator present; auto legitimately routes to jax")
     assert "jax" in sys.modules
@@ -174,10 +174,9 @@ def test_auto_backend_prefers_numpy_on_cpu_only_jax(monkeypatch):
     assert _resolve_backend(None) is None
     # a live DeviceShardView opts auto back into the jitted path
     assert _resolve_backend("auto", device_live=True) is not None
+    assert _resolve_backend(None, device_live=True) is not None
     # explicit request always wins over the CPU heuristic
     assert _resolve_backend("jax") is not None
-    monkeypatch.setenv("SCALANA_DETECT_BACKEND", "jax")
-    assert _resolve_backend(None) is not None
 
 
 def test_proc_mask_excludes_rows_exactly():

@@ -5,9 +5,8 @@ Pins the fused ops (``repro.kernels.detect_fused``) three ways:
 * PARITY — fused-jnp and Pallas-interpret modes against the pure-numpy
   oracle (``ref.py``): flags, winner order and counts EXACT, floats to
   1e-12 in f64 (XLA reassociates sums) and 1e-4 under
-  ``SCALANA_DETECT_F32``; the fused-jnp stacked path is additionally
-  pinned BITWISE against the legacy multi-dispatch kernel chain it
-  replaced (same formulas, same executable shape).
+  ``SCALANA_DETECT_F32``; the view entry points are pinned against the
+  numpy detectors in ``repro.core.detect``.
 * EDGE CASES — empty flag sets, an all-dead scale, degraded fleets
   through the padded live-mask kernel, jit-cache stability across
   live-set sizes (a flapping host must not retrace).
@@ -77,24 +76,6 @@ def test_non_scalable_stacked_matches_oracle(interpret, tag):
         np.testing.assert_allclose(np.asarray(sl), slr, rtol=0, atol=1e-12)
         np.testing.assert_allclose(np.asarray(sh), shr, rtol=0, atol=1e-12)
         np.testing.assert_array_equal(np.asarray(fl), flr)
-
-
-def test_fused_jnp_bitwise_vs_legacy_stacked_kernel():
-    """With an external total the fused-jnp op and the legacy kernel
-    trace the exact same formulas — results must be BITWISE equal."""
-    t, var, present, scales, top = _case(seed=3)
-    tmax = float(t[-1][:, top].max(axis=0, initial=0.0).sum())
-    with enable_x64():
-        logp = jnp.asarray(np.log(np.asarray(scales, np.float64)))
-        got = ops.fused_non_scalable(
-            jnp.asarray(t), jnp.asarray(var), logp, jnp.asarray(present),
-            total_max=tmax, interpret=None, **ARGS)
-        want = detect_jax._non_scalable_kernel(
-            jnp.asarray(t), jnp.asarray(var), logp, jnp.asarray(present),
-            tmax, ARGS["ideal_slope"], ARGS["slope_margin"],
-            ARGS["min_share"])
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 @pytest.mark.parametrize("interpret,tag", MODES)
@@ -256,7 +237,7 @@ def test_all_dead_scale_keeps_finite(interpret, tag):
 def test_fused_live_path_no_retrace_across_live_set_sizes():
     """A flapping host hits ONE compiled fused executable: the live
     gather is padded to the fleet size, so traced shapes depend only on
-    P.  (The legacy kernel has the same pin in test_device_detect.)"""
+    P."""
     t, var, present, scales, top = _case(seed=8)
     P = t.shape[1]
     with enable_x64():
@@ -281,7 +262,7 @@ def test_fused_live_path_no_retrace_across_live_set_sizes():
 
 
 # ---------------------------------------------------------------------------
-# fused == legacy through the view entry points
+# the view entry points against the numpy detectors
 # ---------------------------------------------------------------------------
 
 def _sharded_series(scales=(4, 8, 32), n_hosts=4, straggler=(3, 2, 6.0)):
@@ -296,7 +277,10 @@ def _sharded_series(scales=(4, 8, 32), n_hosts=4, straggler=(3, 2, 6.0)):
                            shards=min(n_hosts, n)).ppg for n in scales}
 
 
-def test_view_entry_points_fused_equals_legacy():
+def test_view_entry_points_match_numpy():
+    """The device-fed view entry points agree with the numpy detectors:
+    flags, merged times, slopes and shares; abnormal winners, ranking
+    and typicals on the full fleet and on a live-row subset."""
     g, series = _sharded_series()
     scales = sorted(series)
     ref_ppg = series[scales[-1]]
@@ -304,32 +288,43 @@ def test_view_entry_points_fused_equals_legacy():
     top = g.children(g.root)
     present = np.ones((len(scales), V), bool)
     views = [series[n].device_view() for n in scales]
-    kw = dict(ideal_slope=0.0, slope_margin=0.05, min_share=0.0,
-              strategy="mean")
-    got = detect_jax.non_scalable_views(scales, views, V, present, top,
-                                        kw["ideal_slope"],
-                                        kw["slope_margin"],
-                                        kw["min_share"], kw["strategy"],
-                                        fused=True)
-    want = detect_jax.non_scalable_views(scales, views, V, present, top,
-                                         kw["ideal_slope"],
-                                         kw["slope_margin"],
-                                         kw["min_share"], kw["strategy"],
-                                         fused=False)
-    np.testing.assert_array_equal(got[3], want[3])          # flags
-    np.testing.assert_allclose(got[0], want[0], rtol=0, atol=1e-12)
-    np.testing.assert_allclose(got[1], want[1], rtol=0, atol=1e-12)
+    for kw in (dict(ideal_slope=0.0, slope_margin=0.05, min_share=0.0),
+               dict(ideal_slope=-1.0, slope_margin=0.35, min_share=0.0)):
+        M, slope, share, flagged = detect_jax.non_scalable_views(
+            scales, views, V, present, top, kw["ideal_slope"],
+            kw["slope_margin"], kw["min_share"], "mean")
+        want = detect_non_scalable(series, backend="numpy",
+                                   strategy="mean", top_k=V, **kw)
+        assert want
+        assert sorted(d.vid for d in want) == \
+            np.flatnonzero(flagged).tolist()
+        for d in want:
+            np.testing.assert_allclose(slope[d.vid], d.slope,
+                                       rtol=0, atol=1e-12)
+            np.testing.assert_allclose(share[d.vid], d.share,
+                                       rtol=0, atol=1e-12)
+            np.testing.assert_allclose(
+                M[:, d.vid], [d.times[n] for n in scales],
+                rtol=0, atol=1e-12)
 
     for live_rows in (None, np.arange(1, ref_ppg.n_procs - 2)):
-        got_ab = detect_jax.abnormal_topk_view(
+        mask = None
+        if live_rows is not None:
+            mask = np.zeros(ref_ppg.n_procs, bool)
+            mask[live_rows] = True
+        vids, procs, typical, _ = detect_jax.abnormal_topk_view(
             ref_ppg.device_view(), V, top, 1.5, 0.001, 8,
-            live_rows=live_rows, fused=True)
-        want_ab = detect_jax.abnormal_topk_view(
-            ref_ppg.device_view(), V, top, 1.5, 0.001, 8,
-            live_rows=live_rows, fused=False)
-        np.testing.assert_array_equal(got_ab[0], want_ab[0])
-        np.testing.assert_array_equal(got_ab[1], want_ab[1])
-        assert got_ab[3] == want_ab[3]
+            live_rows=live_rows)
+        want = detect_abnormal(ref_ppg, abnorm_thd=1.5, min_share=0.001,
+                               top_k=8, backend="numpy", proc_mask=mask)
+        assert want
+        if live_rows is not None:
+            procs = live_rows[procs]             # live-local -> global
+        assert list(zip(vids.tolist(), procs.tolist())) == \
+            [(a.vid, a.proc) for a in want]
+        np.testing.assert_allclose(typical[vids],
+                                   [a.typical for a in want],
+                                   rtol=0, atol=1e-12)
 
 
 # ---------------------------------------------------------------------------

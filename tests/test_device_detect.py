@@ -501,33 +501,6 @@ def test_abnormal_device_proc_mask_equals_numpy_masked():
                                for p, v, t, m in _ab_key(want)]
 
 
-def test_live_kernel_no_retrace_across_live_set_sizes():
-    """A flapping host — a different live COUNT every detect call — must
-    hit one compiled executable: the live gather is padded to the fleet
-    size with a validity mask, so traced shapes depend on P alone.
-    (Regression: the unpadded gather made every live-set size a fresh
-    trace.)"""
-    pytest.importorskip("jax")
-    from repro.core import detect_jax
-
-    n_procs = 16
-    _, plain, sharded = _sim_pair(n_procs, 4, inject={(2, 2): 6.0}, seed=0)
-    kern = detect_jax._abnormal_topk_blocks_live_kernel
-    masks = []
-    for dead in [(3,), (3, 7), (1, 5, 9, 13), (0,), (8, 9, 10, 11, 12)]:
-        mask = np.ones(n_procs, bool)
-        mask[list(dead)] = False
-        masks.append(mask)
-    detect_abnormal(sharded, backend="jax", proc_mask=masks[0])
-    baseline = kern._cache_size()
-    for mask in masks[1:]:
-        got = detect_abnormal(sharded, backend="jax", proc_mask=mask)
-        # parity with the numpy row-subset reference on every mask shape
-        assert _ab_key(got) == _ab_key(
-            detect_abnormal(plain, backend="numpy", proc_mask=mask))
-    assert kern._cache_size() == baseline      # zero retraces
-
-
 def test_device_proc_mask_reuses_buffers_across_masks():
     """Changing the mask between detects must not force a re-upload —
     the live gather happens on device, the pinned buffers stand."""
