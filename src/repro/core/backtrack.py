@@ -31,7 +31,7 @@ import numpy as np
 
 from repro.core.detect import Abnormal, NonScalable
 from repro.core.graph import BRANCH, CALL, COMM, LOOP, PPG, PSG
-from repro.core.spans import spanned
+from repro.core.spans import span, spanned
 
 Node = Tuple[int, int]                     # (proc, vid)
 
@@ -43,6 +43,8 @@ WAIT_EPS = 1e-9
 class Path:
     nodes: List[Node]
     start_reason: str                      # "non_scalable" | "abnormal"
+    # participant waits the walk read by gather (at a collective start)
+    participants: int = dataclasses.field(default=0, compare=False)
 
     @property
     def root_cause(self) -> Node:
@@ -114,14 +116,41 @@ def _comm_partner(ppg: PPG, node: Node, visited) -> Optional[Node]:
     return max(partners, key=lambda n: ppg.get_time(*n))
 
 
-def _latest_participant(ppg: PPG, node: Node) -> Optional[Node]:
+def _waits_at(ppg: PPG, vid: int, procs: np.ndarray) -> np.ndarray:
+    """``wait_s`` of processes ``procs`` at ``vid``, 0.0 where unset: one
+    gather from the counter's compressed columns (the resident stacked
+    view on a sharded store)."""
+    vids, values, mask = ppg.perf.counter_columns(WAIT_COUNTER)
+    slot = np.flatnonzero(vids == vid)
+    if not slot.size:
+        return np.zeros(procs.size)
+    s = int(slot[0])
+    return np.where(mask[procs, s], values[procs, s], 0.0)
+
+
+def _latest_participant(ppg: PPG, node: Node
+                        ) -> Tuple[Optional[Node], int]:
     """For a collective start vertex: the process everyone waited on —
-    the participant with the smallest wait (it arrived last)."""
+    the participant with the smallest wait (it arrived last) — and the
+    number of participant waits read by gather.
+
+    The candidates are ``comm_partners(*node) + [node]`` and the first
+    minimum wins, as ``min`` over that list would: the p2p preds (few,
+    possibly at other vertices) are read one by one, the group peers and
+    ``node`` itself as one gathered column."""
     proc, vid = node
-    group = [p for p in ppg.comm_partners(proc, vid)] + [node]
-    if len(group) <= 1:
-        return None
-    return min(group, key=lambda n: _wait_of(ppg, n))
+    preds = ppg.comm.p2p_preds_of(node)
+    procs = np.append(ppg.comm.group_peers(proc, vid), proc)
+    if not preds and procs.size == 1:
+        return None, 0
+    waits = _waits_at(ppg, vid, procs)
+    i = int(np.argmin(waits))
+    late: Node = (int(procs[i]), vid)
+    if preds:
+        first = min(preds, key=lambda n: _wait_of(ppg, n))
+        if _wait_of(ppg, first) <= waits[i]:
+            late = first
+    return late, int(procs.size)
 
 
 def backtrack_one(ppg: PPG, start: Node, *, reason: str,
@@ -134,6 +163,7 @@ def backtrack_one(ppg: PPG, start: Node, *, reason: str,
     visited = _SeenUnion(scanned, path_set)
     v: Optional[Node] = start
     first = True
+    participants = 0
     while v is not None and len(path) < max_len:
         proc, vid = v
         vert = psg.vertices[vid]
@@ -146,7 +176,8 @@ def backtrack_one(ppg: PPG, start: Node, *, reason: str,
         path_set.add(v)
         nxt: Optional[Node] = None
         if _is_collective(psg, vid):        # collective start vertex
-            late = _latest_participant(ppg, v)
+            late, n_read = _latest_participant(ppg, v)
+            participants += n_read
             if late is not None and late not in visited:
                 nxt = _data_pred(ppg, late, visited) or late
             else:
@@ -163,7 +194,7 @@ def backtrack_one(ppg: PPG, start: Node, *, reason: str,
         first = False
         v = nxt
     scanned.update(path)
-    return Path(nodes=path, start_reason=reason)
+    return Path(nodes=path, start_reason=reason, participants=participants)
 
 
 def _start_nodes(ppg: PPG, non_scalable: Sequence[NonScalable],
@@ -180,20 +211,23 @@ def _start_nodes(ppg: PPG, non_scalable: Sequence[NonScalable],
     return starts
 
 
-@spanned("backtrack")
 def backtrack(ppg: PPG, non_scalable: Sequence[NonScalable],
               abnormal: Sequence[Abnormal]) -> List[Path]:
     """Algorithm 1 Main(): non-scalable starts first, then unscanned
-    abnormal vertices, one sequential scalar walk per start node."""
-    scanned: Set[Node] = set()
-    paths: List[Path] = []
-    for node, reason in _start_nodes(ppg, non_scalable, abnormal):
-        if reason == "abnormal" and node in scanned:
-            continue
-        p = backtrack_one(ppg, node, reason=reason, scanned=scanned)
-        if p.nodes:
-            paths.append(p)
-    return paths
+    abnormal vertices, one sequential scalar walk per start node.  The
+    span's stat ``participants`` counts the participant waits the walks
+    read by gather."""
+    with span("backtrack") as sp:
+        scanned: Set[Node] = set()
+        paths: List[Path] = []
+        for node, reason in _start_nodes(ppg, non_scalable, abnormal):
+            if reason == "abnormal" and node in scanned:
+                continue
+            p = backtrack_one(ppg, node, reason=reason, scanned=scanned)
+            if p.nodes:
+                paths.append(p)
+        sp.set_metadata(participants=sum(p.participants for p in paths))
+        return paths
 
 
 def _anomaly_score(ppg: PPG, node: Node,

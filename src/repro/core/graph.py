@@ -1068,13 +1068,17 @@ class CommIndex:
     """
 
     __slots__ = ("_p2p", "_p2p_preds", "_groups", "_group_sets",
-                 "_p2p_batches")
+                 "_group_arrays", "_p2p_batches")
 
     def __init__(self):
         self._p2p: Set[Tuple[Tuple[int, int], Tuple[int, int]]] = set()
         self._p2p_preds: Dict[Tuple[int, int], List[Tuple[int, int]]] = {}
         self._groups: Dict[int, List[Tuple[int, ...]]] = {}
         self._group_sets: Dict[int, List[frozenset]] = {}
+        # each group's members once, in first-occurrence order, as one
+        # read-only np.intp array: a collective's peers are gathered
+        # without turning a P-tuple into a list per query
+        self._group_arrays: Dict[int, List[np.ndarray]] = {}
         # bulk-registered (vid, src_procs, dst_procs) edge blocks, folded
         # into the explicit set/preds indexes lazily on first query — PPG
         # assembly over an 8k-pair halo ring costs one array append, not
@@ -1113,8 +1117,11 @@ class CommIndex:
         gs = frozenset(group)
         if any(gs == s for s in self._group_sets.get(vid, ())):
             return
+        members = np.fromiter(dict.fromkeys(group), np.intp)
+        members.flags.writeable = False
         self._groups.setdefault(vid, []).append(group)
         self._group_sets.setdefault(vid, []).append(gs)
+        self._group_arrays.setdefault(vid, []).append(members)
 
     # -- queries -------------------------------------------------------
     def groups_of(self, vid: int) -> List[Tuple[int, ...]]:
@@ -1132,24 +1139,34 @@ class CommIndex:
         """Reverse-edge sources of (proc, vid): p2p preds + peers from
         EVERY group containing proc (deduplicated, like the old edge set —
         a vertex can carry several groups, e.g. staged collectives)."""
-        self._materialize_p2p()
-        out = list(self._p2p_preds.get((proc, vid), ()))
-        seen = set(out)
-        for group, gs in zip(self._groups.get(vid, ()),
-                             self._group_sets.get(vid, ())):
-            if proc not in gs:
-                continue
-            for q in group:
-                if q != proc and (q, vid) not in seen:
-                    seen.add((q, vid))
-                    out.append((q, vid))
+        out = list(self.p2p_preds_of((proc, vid)))
+        out.extend((q, vid) for q in self.group_peers(proc, vid).tolist())
         return out
+
+    def group_peers(self, proc: int, vid: int) -> np.ndarray:
+        """The group part of :meth:`partners` as one ``np.intp`` array of
+        processes, in its order: every group containing ``proc`` in
+        registration order, ``proc`` left out, each process once, and
+        none that ``partners`` already lists as a p2p pred at ``vid``."""
+        parts = [m[m != proc] for m, gs in
+                 zip(self._group_arrays.get(vid, ()),
+                     self._group_sets.get(vid, ())) if proc in gs]
+        if not parts:
+            return np.zeros(0, np.intp)
+        peers = parts[0]
+        if len(parts) > 1:                     # overlapping groups
+            peers = np.concatenate(parts)
+            _, first = np.unique(peers, return_index=True)
+            peers = peers[np.sort(first)]
+        preds = [q for q, v in self.p2p_preds_of((proc, vid)) if v == vid]
+        if preds:
+            peers = peers[~np.isin(peers, preds)]
+        return peers
 
     def p2p_preds_of(self, dst: Tuple[int, int]) -> List[Tuple[int, int]]:
         """Explicit p2p reverse-edge sources of ``dst`` in registration
-        order (the internal list — treat as read-only).  The batched
-        backtracker's per-node gather; ``partners`` additionally resolves
-        collective group peers."""
+        order (the internal list — treat as read-only); ``partners``
+        additionally resolves collective group peers."""
         self._materialize_p2p()
         return self._p2p_preds.get(dst, [])
 

@@ -1,11 +1,19 @@
 """Backtracking root-cause detection (Algorithm 1): the paper's core."""
+import importlib
+
 import numpy as np
+import pytest
 
 from repro.core import (COMM, COMP, PSG, backtrack, build_ppg,
                         detect_abnormal, detect_non_scalable, root_causes)
 from repro.core.backtrack import WAIT_COUNTER, backtrack_one
-from repro.core.graph import PerfVector
+from repro.core.detect import Abnormal
+from repro.core.graph import PPG, PerfStore, PerfVector
 from repro.core.inject import simulate, simulate_series
+from repro.core.shard import ShardedStore
+
+# the module itself: ``repro.core.backtrack`` as an attribute is the function
+bt = importlib.import_module("repro.core.backtrack")
 
 
 def _pipeline_psg():
@@ -241,14 +249,23 @@ def test_non_scalable_plus_backtrack_end_to_end():
 # backtrack_one: non-copying scanned-union view (regression)
 # ---------------------------------------------------------------------------
 
+def _latest_participant_scalar(ppg, node):
+    """The scalar reference: ``min`` over ``comm_partners + [node]`` by
+    each candidate's own ``counter_at`` read (first minimum wins)."""
+    group = ppg.comm_partners(*node) + [node]
+    if len(group) <= 1:
+        return None
+    return min(group, key=lambda n: bt._wait_of(ppg, n))
+
+
 def _backtrack_one_copying(ppg, start, *, reason, scanned, max_len=256):
     """The pre-fix reference walk: rebuilds ``scanned | set(path)`` on
-    every step.  Retained here to pin the union-view rewrite to the old
-    semantics exactly."""
+    every step and picks a collective's latest participant by scalar
+    reads.  Retained here to pin the walk to the old semantics
+    exactly."""
     from repro.core.backtrack import (Path, WAIT_EPS, _comm_partner,
                                       _control_end, _data_pred,
-                                      _is_collective, _is_p2p,
-                                      _latest_participant, _wait_of)
+                                      _is_collective, _is_p2p, _wait_of)
     from repro.core.graph import BRANCH, CALL, LOOP
     psg = ppg.psg
     path = []
@@ -266,7 +283,7 @@ def _backtrack_one_copying(ppg, start, *, reason, scanned, max_len=256):
         nxt = None
         visited = scanned | set(path)            # the quadratic copy
         if _is_collective(psg, vid):
-            late = _latest_participant(ppg, v)
+            late = _latest_participant_scalar(ppg, v)
             if late is not None and late not in visited:
                 nxt = _data_pred(ppg, late, visited) or late
             else:
@@ -313,3 +330,105 @@ def test_backtrack_one_union_view_matches_copying_reference():
                                          scanned=scanned_ref)
             assert got.nodes == ref.nodes, trial
         assert scanned_new == scanned_ref
+
+
+# ---------------------------------------------------------------------------
+# collective start: the latest participant by one gathered wait column
+# ---------------------------------------------------------------------------
+
+def _collective_ppg(rng, store):
+    """A PPG over ``n`` processes with four collective vertices: ``a``
+    carries overlapping groups (one of them a lone repeated process, which
+    leaves no peers) that some processes are in none of, ``b`` one group,
+    both with p2p reverse edges from ``a``, ``b`` or ``c``; ``c`` has no
+    ``wait_s`` column at all, ``d`` one group whose waits are all unset.
+    Waits come from {0.0, 0.1, 0.2} on a random subset: ties throughout."""
+    n = int(rng.integers(6, 40))
+    g = PSG()
+    root = g.new_vertex("Root", "root")
+    g.root = root.vid
+    vids = []
+    for name in "abcd":
+        v = g.new_vertex(COMM, name, parent=root.vid)
+        v.comm_kind = "all_reduce"
+        vids.append(v.vid)
+    a, b, c, d = vids
+    if store == "plain":
+        perf = PerfStore(n, len(g.vertices))
+    else:                                   # uneven shard ranges
+        cuts = sorted(rng.choice(np.arange(1, n), size=int(rng.integers(
+            1, min(n - 1, 8))), replace=False).tolist())
+        perf = ShardedStore(list(zip([0] + cuts, cuts + [n])),
+                            len(g.vertices))
+    ppg = PPG(g, n, perf=perf)
+    for _ in range(int(rng.integers(2, 5))):
+        k = int(rng.integers(2, n))
+        ppg.add_collective_edges(a, rng.choice(n, size=k, replace=False))
+    lone = int(rng.integers(0, n))
+    ppg.add_collective_edges(a, [lone, lone])
+    ppg.add_collective_edges(b, range(n))
+    for _ in range(int(rng.integers(1, 6))):
+        src = (int(rng.integers(0, n)), int(rng.choice([a, b, c])))
+        dst = (int(rng.integers(0, n)), int(rng.choice([a, b])))
+        ppg.add_p2p_edge(*src, *dst)
+    ppg.add_collective_edges(c, rng.permutation(n))
+    ppg.add_collective_edges(d, range(0, n, 2))
+    for vid in vids:
+        perf.set_column(vid, 0.01)
+    for vid in (a, b, d):
+        procs = rng.choice(n, size=int(rng.integers(0, n)), replace=False)
+        waits = rng.choice([0.0, 0.1, 0.2], size=procs.size)
+        if vid == d:
+            procs, waits = np.zeros(0, np.intp), np.zeros(0)
+        perf.set_entries(procs, vid, 0.01, counters={WAIT_COUNTER: waits})
+    perf.set_entries([0], a, 0.01, counters={"other": 1.0})
+    return ppg, vids
+
+
+@pytest.mark.parametrize("store", ["plain", "sharded"])
+def test_latest_participant_equals_scalar_min(store):
+    """The gathered first minimum equals ``min`` over the scalar reads,
+    node for node, and counts the participants it gathered: the group
+    peers and the node itself, 0 where there is no partner (``None``)."""
+    rng = np.random.default_rng(18)
+    nones = 0
+    for trial in range(30):
+        ppg, vids = _collective_ppg(rng, store)
+        for vid in vids:
+            for p in range(ppg.n_procs):
+                node = (p, vid)
+                ref = _latest_participant_scalar(ppg, node)
+                late, n_read = bt._latest_participant(ppg, node)
+                assert late == ref, (trial, node)
+                nones += ref is None
+                want = 0 if ref is None else (
+                    len(ppg.comm_partners(*node))
+                    - len(ppg.comm.p2p_preds_of(node)) + 1)
+                assert n_read == want, (trial, node)
+    assert nones                       # ungrouped and lone processes
+
+
+def test_backtrack_straggler_at_all_reduce_on_512_procs_128_shards(
+        monkeypatch):
+    """End to end on a sharded fleet: a straggler everyone waits on at
+    the all-reduce.  The walks equal those that pick the latest
+    participant by scalar reads, the all-reduce start gathers all 512
+    waits, and a walk that reaches no collective start gathers none."""
+    g, (c0, c1, p2p, c2, ar) = _pipeline_psg()
+    res = simulate(g, 512, lambda p, vid: 0.01, inject={(300, c2): 0.4},
+                   shards=128)
+    ppg = res.ppg
+    assert isinstance(ppg.perf, ShardedStore)
+    assert len(ppg.perf.shards) == 128
+    ab = detect_abnormal(ppg, top_k=50)
+    starts = [Abnormal(vid=ar, proc=7, time=0.0, typical=0.0, ratio=1.0)]
+    starts += ab
+    got = backtrack(ppg, [], starts)
+    assert got[0].nodes[:2] == [(7, ar), (300, c2)]
+    assert got[0].participants == 512
+    assert sum(p.participants for p in got) == 512
+    assert backtrack_one(ppg, (5, c2), reason="abnormal",
+                         scanned=set()).participants == 0
+    monkeypatch.setattr(bt, "_latest_participant", lambda ppg_, node: (
+        _latest_participant_scalar(ppg_, node), 0))
+    assert _paths_key(backtrack(ppg, [], starts)) == _paths_key(got)

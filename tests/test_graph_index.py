@@ -288,6 +288,57 @@ def test_comm_partners_unions_overlapping_groups():
     assert sorted(ppg.comm_partners(3, c.vid)) == [(0, c.vid), (1, c.vid)]
 
 
+def _partners_reference(ci, proc, vid):
+    """``CommIndex.partners`` as it read before the group arrays: the p2p
+    preds, then each group's peers of ``proc`` through one seen set."""
+    out = list(ci.p2p_preds_of((proc, vid)))
+    seen = set(out)
+    for group in ci.groups_of(vid):
+        if proc not in group:
+            continue
+        for q in group:
+            if q != proc and (q, vid) not in seen:
+                seen.add((q, vid))
+                out.append((q, vid))
+    return out
+
+
+def test_group_arrays_and_peers_match_the_group_tuples():
+    """Each group's cached array is its tuple, each process once in
+    first-occurrence order; ``group_peers`` is the group part of
+    ``partners``, and ``partners`` is the old seen-set union, order
+    included — with overlapping groups, repeated members and p2p preds
+    at the same and at other vertices."""
+    from repro.core.graph import CommIndex
+    rng = np.random.default_rng(3)
+    for trial in range(40):
+        ci = CommIndex()
+        n = int(rng.integers(2, 30))
+        for vid in range(3):
+            for _ in range(int(rng.integers(0, 4))):
+                members = rng.choice(n, size=int(rng.integers(1, n + 1)),
+                                     replace=bool(rng.random() < 0.3))
+                ci.add_group(vid, members.tolist())
+        for _ in range(int(rng.integers(0, 8))):
+            ci.add_p2p((int(rng.integers(0, n)), int(rng.integers(0, 3))),
+                       (int(rng.integers(0, n)), int(rng.integers(0, 3))))
+        ci.add_p2p_batch(1, rng.integers(0, n, 3), rng.integers(0, n, 3))
+        for vid in range(3):
+            arrays = ci._group_arrays.get(vid, [])
+            assert [a.tolist() for a in arrays] == \
+                [list(dict.fromkeys(t)) for t in ci.groups_of(vid)], trial
+            assert all(a.dtype == np.intp and not a.flags.writeable
+                       for a in arrays)
+            for p in range(n):
+                ref = _partners_reference(ci, p, vid)
+                assert ci.partners(p, vid) == ref, (trial, p, vid)
+                peers = ci.group_peers(p, vid)
+                assert peers.dtype == np.intp
+                n_preds = len(ci.p2p_preds_of((p, vid)))
+                assert [(q, vid) for q in peers.tolist()] == \
+                    ref[n_preds:], (trial, p, vid)
+
+
 def test_collective_storage_is_linear_in_procs():
     def comm_bytes(n):
         g = PSG()

@@ -53,6 +53,7 @@ STATS = {
     "detect.concat": {"operands"},
     "store.stack": {"shards", "restacked"},
     "detect.abnormal": {"col_tiles"},
+    "backtrack": {"participants"},
 }
 
 
